@@ -1724,6 +1724,8 @@ def main(argv=None) -> int:
                     help="with --check: refresh the committed"
                          " fast_baseline stanza instead of enforcing it")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     FAST = args.fast
     JSON_DIR = pathlib.Path(args.json_dir)
     JSON_DIR.mkdir(parents=True, exist_ok=True)

@@ -2,9 +2,7 @@
 
 A serving deployment is R replicas x TP chips. Scaling re-builds the mesh
 as (R', TP), re-lowers prefill/decode, and reshards params onto the new
-topology (device_put through the checkpoint/restore path — the same code
-path that handles node-failure recovery, so elasticity and fault tolerance
-are one mechanism).
+topology (device_put from the current mesh onto the new one).
 
 Compiled artifacts are cached per (replicas, tp): scaling back to a
 previously-seen size reuses the mesh, the jitted prefill/decode closures
@@ -14,11 +12,11 @@ donates its cache argument, so the per-token KV update is in-place
 instead of a full slab copy per step."""
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
 
 import jax
-import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.launch.mesh import make_mesh
@@ -43,7 +41,12 @@ class ElasticServing:
     _kernels: Dict[tuple, object] = field(default_factory=dict)
 
     def max_replicas(self) -> int:
-        return max(len(jax.devices()) // self.tp, 1)
+        n = len(jax.devices())
+        if self.tp > n:
+            raise ValueError(f"tp={self.tp} needs {self.tp} devices per "
+                             f"replica; the {jax.default_backend()} "
+                             f"platform has {n}")
+        return n // self.tp
 
     def _lowered(self, replicas: int):
         key = (replicas, self.tp)
@@ -67,14 +70,26 @@ class ElasticServing:
         self._compiled[key] = entry
         return entry
 
-    def build(self, replicas: int, host_params=None, now: float = 0.0):
-        """(Re)build at ``replicas`` data-parallel replicas."""
+    def build(self, replicas: int, host_params=None, now: float = 0.0,
+              seed: Optional[int] = None):
+        """(Re)build at ``replicas`` data-parallel replicas. Parameters come
+        from ``host_params``, or are drawn on the devices from ``seed``
+        (each device builds only its own shard), or else are the current
+        ones, moved onto the new mesh."""
         replicas = min(max(replicas, 1), self.max_replicas())
-        if host_params is None:
-            host_params = self.host_params()
         mesh, ctx, prefill_fn, decode_fn, psh = self._lowered(replicas)
-        params = jax.tree.map(
-            lambda h, s: jax.device_put(h, s), host_params, psh)
+        if host_params is not None:
+            params = jax.tree.map(jax.device_put, host_params, psh)
+        elif seed is not None:
+            mod = MA.get_module(self.cfg)
+            init = functools.partial(mod.init, cfg=self.cfg)
+            params = jax.jit(init, out_shardings=psh)(
+                jax.random.PRNGKey(seed))
+        elif self.params is not None:
+            params = jax.tree.map(jax.device_put, self.params, psh)
+        else:
+            raise RuntimeError("no params yet — build with host_params or "
+                               "seed")
         self.prefill_fn, self.decode_fn = prefill_fn, decode_fn
         old = self.replicas
         self.mesh, self.ctx, self.params = mesh, ctx, params
@@ -92,11 +107,6 @@ class ElasticServing:
         if key not in self._kernels:
             self._kernels[key] = RuntimeKernels(self.cfg, rcfg, self.ctx)
         return self._kernels[key]
-
-    def host_params(self):
-        if self.params is None:
-            raise RuntimeError("no params yet — call build(host_params=...)")
-        return jax.tree.map(np.asarray, self.params)
 
     def scale_to(self, replicas: int, now: float = 0.0):
         replicas = min(max(replicas, 1), self.max_replicas())

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention import decode_attention_kernel
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mlstm_scan import mlstm_chunkwise_kernel
-from repro.kernels.paged_decode_attention import paged_decode_attention_kernel
+from repro.kernels.paged_decode_attention import (
+    paged_decode_attention_kernel, paged_window_attention_kernel)
 from repro.kernels.ssm_scan import ssm_scan_kernel
 
 KERNEL_MODES = ("auto", "pallas", "jnp")
@@ -92,32 +93,65 @@ def decode_attention_model(q, k_cache, v_cache, *, pos, window=None,
                             softcap=softcap, block_skip=block_skip)
 
 
+def _paged_kernel_call(kernel, q, k_pool, v_pool, pages, lengths, ctx,
+                       **kw):
+    """Run a paged Pallas kernel, on a mesh one shard per device.
+
+    GSPMD cannot partition a ``pallas_call``, so on a mesh the kernel runs
+    under ``shard_map``: the pool's KV-head axis (and the query heads of
+    each KV group with it) is split the way the pool is committed
+    (``model_api.paged_cache_axes``), everything else is replicated. Each
+    device then attends over its own KV heads; no collective is needed."""
+    call = functools.partial(kernel, interpret=_interpret(), **kw)
+    if ctx is None or ctx.mesh is None:
+        return call(q, k_pool, v_pool, pages, lengths)
+    from jax.sharding import PartitionSpec as P
+    spec = ctx.spec((None, "kv_heads"), k_pool.shape)
+    ax = spec[1] if len(spec) > 1 else None
+    q_spec = P(*([None] * (q.ndim - 2)), ax, None)
+    pool_spec = P(None, ax)
+    return jax.shard_map(call, mesh=ctx.mesh,
+                         in_specs=(q_spec, pool_spec, pool_spec, P(), P()),
+                         out_specs=q_spec, check_vma=False)(
+        q, k_pool, v_pool, pages, lengths)
+
+
+def _gather_pages(pool, pages, kv_bucket):
+    """First kv_bucket entries of each row, gathered from the head-major
+    pool: (n_pages, Hkv, ps, dh) -> (B, kv_bucket, Hkv, dh)."""
+    B = pages.shape[0]
+    g = pool[pages].transpose(0, 1, 3, 2, 4)      # (B, npg, ps, Hkv, dh)
+    return g.reshape(B, kv_bucket, pool.shape[1], pool.shape[3])
+
+
 def decode_attention_paged(q, k_pool, v_pool, pages, lengths, *, kv_bucket,
-                           page_size, window=None, chunk=None, softcap=0.0):
+                           page_size, window=None, chunk=None, softcap=0.0,
+                           ctx=None):
     """Decode attention for the paged layout.
 
-    q: (B,1,Hq,dh); pools: (n_pages, page_size, Hkv, dh); pages: (B,P)
-    physical-page table; lengths: (B,) live entries per row. ``kv_bucket``
-    (static, a multiple of page_size) bounds how many *logical* entries the
-    jnp path materializes — the host picks the smallest bucket covering the
-    deepest live row, so gather cost tracks live tokens, not capacity.
+    q: (B,1,Hq,dh); pools: (n_pages, Hkv, page_size, dh), head-major;
+    pages: (B,P) physical-page table; lengths: (B,) live entries per row.
+    ``kv_bucket`` (static, a multiple of page_size) bounds how many
+    *logical* entries are read — the host picks the smallest bucket
+    covering the deepest live row, so read cost tracks live tokens, not
+    capacity. ``ctx`` carries the serving mesh (see
+    ``_paged_kernel_call``).
 
-    pallas mode: the paged kernel reads pages straight from the pool via
-    scalar-prefetch indexing (no gather) and early-exits each row's page
-    grid. jnp mode: gather the first kv_bucket//page_size pages per row and
-    run the block-skip streaming decode over them.
+    pallas mode: the paged kernel reads the bucket's pages straight from
+    the pool via scalar-prefetch indexing (no gather) and skips each
+    row's pages past its length. jnp mode: gather the bucket's pages per
+    row and run the block-skip streaming decode over them.
     """
-    if resolved_mode() == "pallas" and not softcap:
-        out = paged_decode_attention_kernel(
-            q[:, 0], k_pool, v_pool, pages, lengths,
-            window=window, chunk=chunk, interpret=_interpret())
-        return out[:, None]
-    from repro.models.attention import decode_attention
-    B = q.shape[0]
     npg = kv_bucket // page_size
     pid = pages[:, :npg]                                   # (B, npg)
-    kb = k_pool[pid].reshape(B, kv_bucket, *k_pool.shape[2:])
-    vb = v_pool[pid].reshape(B, kv_bucket, *v_pool.shape[2:])
+    if use_kernels():
+        out = _paged_kernel_call(paged_decode_attention_kernel, q[:, 0],
+                                 k_pool, v_pool, pid, lengths, ctx,
+                                 window=window, chunk=chunk, softcap=softcap)
+        return out[:, None]
+    from repro.models.attention import decode_attention
+    kb = _gather_pages(k_pool, pid, kv_bucket)
+    vb = _gather_pages(v_pool, pid, kv_bucket)
     # the gathered width is already bucketed to the deepest live row, so
     # intra-bucket skipping only pays once the bucket spans several pages
     skip = page_size if npg >= 4 else None
@@ -126,7 +160,8 @@ def decode_attention_paged(q, k_pool, v_pool, pages, lengths, *, kv_bucket,
 
 
 def window_attention_paged(q, k_pool, v_pool, pages, pos, *, kv_bucket,
-                           page_size, window=None, chunk=None, softcap=0.0):
+                           page_size, window=None, chunk=None, softcap=0.0,
+                           ctx=None):
     """W-token decode-window attention for the paged layout.
 
     q: (B,W,Hq,dh) — W consecutive new positions per row, whose KV the
@@ -134,25 +169,21 @@ def window_attention_paged(q, k_pool, v_pool, pages, pos, *, kv_bucket,
     pos: (B,) each row's first new position. Serves the prefix-cache tail
     prefill and the speculative-decode verify dispatch.
 
-    pallas mode: W calls of the untouched 1-token paged kernel, one per
-    window offset (offset w attends through pos+w) — the kernel's
-    page-table indirection already covers the freshly written entries.
-    jnp mode: one page gather + blockwise attention with per-offset
-    causal masking over the kv_bucket.
+    pallas mode: ``paged_window_attention_kernel`` (one 1-token kernel
+    call per window offset; offset w attends through pos+w). jnp mode:
+    one page gather + blockwise attention with per-offset causal masking
+    over the kv_bucket.
     """
-    if resolved_mode() == "pallas" and not softcap:
-        W = q.shape[1]
-        outs = [paged_decode_attention_kernel(
-                    q[:, w], k_pool, v_pool, pages, pos + w + 1,
-                    window=window, chunk=chunk, interpret=_interpret())
-                for w in range(W)]
-        return jnp.stack(outs, axis=1)
-    from repro.models.attention import blockwise_attention
-    B, W = q.shape[:2]
     npg = kv_bucket // page_size
     pid = pages[:, :npg]                                   # (B, npg)
-    kb = k_pool[pid].reshape(B, kv_bucket, *k_pool.shape[2:])
-    vb = v_pool[pid].reshape(B, kv_bucket, *v_pool.shape[2:])
+    if use_kernels():
+        return _paged_kernel_call(paged_window_attention_kernel, q, k_pool,
+                                  v_pool, pid, pos, ctx, window=window,
+                                  chunk=chunk, softcap=softcap)
+    from repro.models.attention import blockwise_attention
+    B, W = q.shape[:2]
+    kb = _gather_pages(k_pool, pid, kv_bucket)
+    vb = _gather_pages(v_pool, pid, kv_bucket)
     q_pos = pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     kv_pos = jnp.broadcast_to(
         jnp.arange(kv_bucket, dtype=jnp.int32)[None, :], (B, kv_bucket))

@@ -19,7 +19,7 @@ import traceback
 import jax
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config, shapes_for
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import make_cell
 from repro.models import model_api as MA
 from repro.roofline import analysis as RA
@@ -38,7 +38,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir=OUT_DIR,
         mesh = make_production_mesh(multi_pod=multi_pod)
         kw = dict(overrides or {})
         cell = make_cell(cfg, shape, mesh, **kw)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = cell.lower()
             rec["lower_s"] = round(time.time() - t0, 2)
             t1 = time.time()
@@ -46,8 +46,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir=OUT_DIR,
             rec["compile_s"] = round(time.time() - t1, 2)
             print(compiled.memory_analysis())
             cost = compiled.cost_analysis()
-            if isinstance(cost, list):  # older API returned [dict]
-                cost = cost[0] if cost else {}
             print({k: v for k, v in cost.items()
                    if k in ("flops", "bytes accessed", "transcendentals")})
             rec.update(RA.from_compiled(compiled))

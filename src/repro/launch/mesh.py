@@ -5,22 +5,11 @@ from __future__ import annotations
 import jax
 
 
-def _mesh(shape, axes):
-    """``axis_types`` only exists on newer jax; older installs default to
-    Auto axes anyway, so omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def set_mesh(mesh):
-    """``jax.set_mesh`` appeared in newer jax; on older installs the Mesh
-    object itself is the equivalent context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axes):
+    """Mesh of the given shape over this process's devices, Auto axes
+    (GSPMD propagates shardings; e.g. (4, 2) for elastic re-meshing)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,9 +18,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis carries hierarchical data parallelism across the ICI/DCN boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests / elastic re-meshing (e.g. (4, 2))."""
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)

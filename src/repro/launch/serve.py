@@ -37,9 +37,14 @@ copy-on-write on divergence); ``--spec-decode K`` drafts K tokens per
 row and verifies them in one (K+1)-wide dispatch. Both require
 ``--paged``; end-of-run stats report hit and accept rates.
 
+On the CPU (tests), ``--reduced`` serves the family-preserving tiny
+config and ``--devices N`` forges N host devices; on a TPU the devices
+are the chips there are, and asking for more is an error at startup.
+
 Usage:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --devices 8 \
-      --tp 2 --nodes 4 --ticks 80 [--controller hpa] [--walltime 300] \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --reduced \
+      --devices 8 --tp 2 --nodes 4 --ticks 80 [--controller hpa] \
+      [--walltime 300] \
       [--sites "jlab:2,nersc:2" --site-latency "jlab:nersc:40" \
        --kill-site jlab --kill-tick 40] \
       [--batch-load 6 --quota "ersap:chips=6,batch:chips=6"]
@@ -51,6 +56,8 @@ import sys
 
 
 def _pre_jax():
+    # host-platform device count must be fixed before jax import; a TPU
+    # platform ignores it and reports its own chips
     if "--devices" in sys.argv:
         n = sys.argv[sys.argv.index("--devices") + 1]
         os.environ.setdefault("XLA_FLAGS",
@@ -75,15 +82,20 @@ from repro.core.jrm import SliceSpec              # noqa: E402
 from repro.core.scheduler import Scheduler, SiteTopology  # noqa: E402
 from repro.core.digital_twin.queue_model import ground_truth, lam_of_state  # noqa: E402
 from repro.data.pipeline import RequestSource     # noqa: E402
-from repro.models import model_api as MA          # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.streaming.engine import StreamEngine   # noqa: E402
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--tp", type=int, default=2)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the family-preserving tiny config (CPU"
+                         " tests) instead of the published widths")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="devices of the slice (0 = every device of the"
+                         " platform)")
+    ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--nodes", type=int, default=4)
     ap.add_argument("--ticks", type=int, default=80)
     ap.add_argument("--dt", type=float, default=10.0)
@@ -235,7 +247,17 @@ def main(argv=None):
         if args.kill_site not in known:
             ap.error(f"--kill-site {args.kill_site!r} not in --sites spec")
 
-    cfg = get_config(args.arch).reduced()
+    n_dev = len(jax.devices())
+    args.devices = args.devices or n_dev
+    if args.devices > n_dev:
+        ap.error(f"--devices {args.devices}: the {jax.default_backend()} "
+                 f"platform has {n_dev}")
+    if args.tp > args.devices:
+        ap.error(f"--tp {args.tp} exceeds --devices {args.devices}")
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
 
     # kernel dispatch is resolved once, before any jit closure is traced
     from repro.kernels import ops as OPS
@@ -288,11 +310,12 @@ def main(argv=None):
           f"{len(fm.available())} ready nodes")
 
     # ---- model + elastic serving ----
-    mod = MA.get_module(cfg)
-    host_params = jax.tree.map(np.asarray,
-                               mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=args.tp)
-    serving.build(1, host_params=host_params)
+    serving = ElasticServing(cfg, tp=args.tp).build(1, seed=0)
+    n_bytes = sum(x.nbytes for x in jax.tree.leaves(serving.params))
+    print(f"[model] {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, {cfg.n_heads}/{cfg.n_kv_heads} heads, vocab "
+          f"{cfg.vocab}; {n_bytes / 1e9:.2f} GB of {cfg.dtype} weights "
+          f"over {args.tp} device(s) per replica")
     # service rate per replica = mu(16 threads) scaled like the arrivals, so
     # one replica is near-critical at high pressure (M/M/1 analog) and the
     # twin's 2x escalation actually drains the queue.

@@ -12,12 +12,14 @@ Usage:
       --ckpt-dir /tmp/ckpt [--kill-at-step 30] [--walltime 120]
 """
 import argparse
+import math
 import os
 import sys
 
 
 def _pre_jax():
-    # device count must be fixed before jax import
+    # host-platform device count must be fixed before jax import; a TPU
+    # platform ignores it and reports its own chips
     if "--devices" in sys.argv:
         n = sys.argv[sys.argv.index("--devices") + 1]
         os.environ.setdefault("XLA_FLAGS",
@@ -35,6 +37,7 @@ from repro.configs.base import ShapeConfig, get_config      # noqa: E402
 from repro.core.jrm import start_vk                         # noqa: E402
 from repro.core.metrics import Registry                     # noqa: E402
 from repro.data.pipeline import DataConfig, SyntheticDataset  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh                     # noqa: E402
 from repro.launch.steps import make_train_cell              # noqa: E402
 from repro.models import model_api as MA                    # noqa: E402
@@ -60,14 +63,22 @@ def main(argv=None):
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args(argv)
 
+    n_dev = len(jax.devices())
+    if args.devices > n_dev:
+        ap.error(f"--devices {args.devices}: the {jax.default_backend()} "
+                 f"platform has {n_dev}")
+    dims = tuple(int(x) for x in args.mesh.split("x")) if args.mesh else ()
+    if math.prod(dims) > n_dev:
+        ap.error(f"--mesh {args.mesh} needs {math.prod(dims)} devices; the "
+                 f"{jax.default_backend()} platform has {n_dev}")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     shape = ShapeConfig("cli", "train", args.seq, args.batch)
 
     mesh = None
-    if args.mesh:
-        dims = tuple(int(x) for x in args.mesh.split("x"))
+    if dims:
         axes = ("data", "model")[:len(dims)] if len(dims) == 2 else \
             ("pod", "data", "model")[:len(dims)]
         mesh = make_mesh(dims, axes)

@@ -197,14 +197,31 @@ def scatter_prefill(cfg: ArchConfig, slab, prefill_cache, slot_idx, seq_len):
 def init_paged_cache(cfg: ArchConfig, rows: int, n_pages: int,
                      page_size: int):
     """Paged decode cache: a shared physical pool of ``n_pages`` KV pages
-    of ``page_size`` entries per layer ((L, n_pages, page_size, kvh, dh)),
-    plus a per-row position vector for ``rows`` slots. Which pages a row
-    owns lives host-side (the runtime's page table / allocator); physical
-    page 0 is reserved as the null page. HBM scales with the pool, not
-    with rows x capacity."""
-    cache = get_module(cfg).init_cache(cfg, n_pages, page_size)
-    cache["pos"] = jnp.zeros((rows,), jnp.int32)
+    of ``page_size`` entries per layer, head-major
+    ((L, n_pages, kvh, page_size, dh) — the block layout the paged Pallas
+    kernel reads), plus a per-row position vector for ``rows`` slots.
+    Which pages a row owns lives host-side (the runtime's page table /
+    allocator); physical page 0 is reserved as the null page. HBM scales
+    with the pool, not with rows x capacity."""
+    n_moe = 0 if cfg.moe is None else cfg.n_layers - cfg.moe.first_k_dense
+    shape = lambda n: (n, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+    cache = {"pos": jnp.zeros((rows,), jnp.int32)}
+    for part, n in (("dense", cfg.n_layers - n_moe), ("moe", n_moe)):
+        if n:
+            cache[part] = {"k": jnp.zeros(shape(n), cfg.jdtype),
+                           "v": jnp.zeros(shape(n), cfg.jdtype)}
     return cache
+
+
+def paged_cache_axes(cfg: ArchConfig):
+    """Logical axes of ``init_paged_cache``: the pool splits over KV heads
+    (each chip of a tensor-parallel replica holds its heads' pages, which
+    is what the paged kernel's ``shard_map`` expects); positions are
+    replicated."""
+    shapes = jax.eval_shape(lambda: init_paged_cache(cfg, 1, 1, 1))
+    axes = jax.tree.map(lambda _: P(None, None, "kv_heads"), shapes)
+    axes["pos"] = P()
+    return axes
 
 
 def scatter_prefill_paged(cfg: ArchConfig, slab, prefill_cache, slot_idx,
@@ -224,14 +241,14 @@ def scatter_prefill_paged(cfg: ArchConfig, slab, prefill_cache, slot_idx,
         dst = dict(slab[part])
         for nm in ("k", "v"):
             src = prefill_cache[part][nm]          # (L, B, S, kvh, dh)
-            L, B, S = src.shape[:3]
+            L, B, S, kvh, dh = src.shape
             pad = npg * page_size - S
             if pad:
                 src = jnp.pad(src, ((0, 0), (0, 0), (0, pad),
                                     (0, 0), (0, 0)))
-            src = src.reshape(L, B * npg, page_size, *src.shape[3:])
+            src = src.reshape(L, B * npg, page_size, kvh, dh)
             dst[nm] = slab[part][nm].at[:, flat].set(
-                src.astype(slab[part][nm].dtype))
+                src.swapaxes(2, 3).astype(slab[part][nm].dtype))
         new[part] = dst
     new["pos"] = slab["pos"].at[slot_idx].set(jnp.int32(seq_len))
     return new

@@ -355,8 +355,8 @@ def decode_step(params, token, cache, cfg: ArchConfig, ctx=None,
     share one trace.
 
     Paged layout: when ``pages`` ((B, P) int32 physical-page table) is
-    given, per-layer caches are shared pools of fixed-size KV pages
-    ((n_pages, page_size, kvh, dh)) instead of per-row slabs. The new
+    given, per-layer caches are shared pools of fixed-size KV pages,
+    head-major ((n_pages, kvh, page_size, dh)), instead of per-row slabs. The new
     token's KV scatters into the row's current page, and attention reads
     only the first ``kv_bucket`` logical entries (static, host-picked to
     cover the deepest live row) through ``kernels.ops`` — so decode cost
@@ -388,7 +388,7 @@ def decode_step(params, token, cache, cfg: ArchConfig, ctx=None,
     def run(stacked, kc, vc, use_moe):
         nonlocal x
         if paged:
-            page_size = kc.shape[2]
+            page_size = kc.shape[3]
             lp = pos_b // page_size                      # logical page
             off = pos_b % page_size                      # offset within it
             phys = jnp.take_along_axis(pages, lp[:, None], axis=1)[:, 0]
@@ -408,12 +408,12 @@ def decode_step(params, token, cache, cfg: ArchConfig, ctx=None,
             k, v = _kv_proj(h, blk, cfg, positions)
             if paged:
                 # scatter the new KV into each row's current physical page
-                k_l = k_l.at[phys, off].set(k[:, 0].astype(k_l.dtype))
-                v_l = v_l.at[phys, off].set(v[:, 0].astype(v_l.dtype))
+                k_l = k_l.at[phys, :, off].set(k[:, 0].astype(k_l.dtype))
+                v_l = v_l.at[phys, :, off].set(v[:, 0].astype(v_l.dtype))
                 out = OPS.decode_attention_paged(
                     q, k_l, v_l, pages, pos_b + 1, kv_bucket=kv_bucket,
                     page_size=page_size, window=cfg.sliding_window,
-                    chunk=cfg.attn_chunk, softcap=cfg.logit_softcap)
+                    chunk=cfg.attn_chunk, softcap=cfg.logit_softcap, ctx=ctx)
             else:
                 # explicit masked write instead of dynamic_update_slice: on
                 # a slot-sharded cache GSPMD lowers DUS to a masked select
@@ -495,7 +495,7 @@ def decode_window(params, tokens, cache, cfg: ArchConfig, ctx=None, *,
 
     def run(stacked, kc, vc, use_moe):
         nonlocal x
-        page_size = kc.shape[2]
+        page_size = kc.shape[3]
         lp = positions // page_size                     # (B, W) logical page
         off = positions % page_size
         phys = jnp.take_along_axis(pages, lp, axis=1)   # (B, W) physical
@@ -510,12 +510,12 @@ def decode_window(params, tokens, cache, cfg: ArchConfig, ctx=None, *,
             q = q.reshape(B, W, cfg.n_heads, cfg.head_dim)
             q = L.apply_rope(q, positions, cfg.rope_theta)
             k, v = _kv_proj(h, blk, cfg, positions)
-            k_l = k_l.at[phys, off].set(k.astype(k_l.dtype))
-            v_l = v_l.at[phys, off].set(v.astype(v_l.dtype))
+            k_l = k_l.at[phys, :, off].set(k.astype(k_l.dtype))
+            v_l = v_l.at[phys, :, off].set(v.astype(v_l.dtype))
             out = OPS.window_attention_paged(
                 q, k_l, v_l, pages, pos_b, kv_bucket=kv_bucket,
                 page_size=page_size, window=cfg.sliding_window,
-                chunk=cfg.attn_chunk, softcap=cfg.logit_softcap)
+                chunk=cfg.attn_chunk, softcap=cfg.logit_softcap, ctx=ctx)
             out = out.reshape(B, W, cfg.q_dim)
             xx = xx + jnp.einsum("bsq,qd->bsd", out, blk["wo"].astype(h.dtype))
             h2 = L.rms_norm(xx, blk["ln2"], cfg.norm_eps)

@@ -140,8 +140,6 @@ def from_compiled(compiled) -> dict:
     from repro.roofline import hlo_graph
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older API returned [dict]
-        cost = cost[0]
     hlo = compiled.as_text()
     la = hlo_graph.analyze(hlo)
     rl = Roofline(la.dot_flops, la.traffic_bytes, la.wire_bytes)

@@ -99,8 +99,7 @@ def main():
     cfg = get_config(args.arch)
     mesh = make_production_mesh(multi_pod=args.multipod)
     cell = make_cell(cfg, SHAPES[args.shape], mesh, **overrides)
-    from repro.launch.mesh import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hlo = cell.lower().compile().as_text()
     traffic, colls = walk_items(hlo)
     traffic.sort(reverse=True)

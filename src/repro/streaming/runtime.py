@@ -469,7 +469,7 @@ class RuntimeKernels:
                     continue
                 d = dict(cache[part])
                 for nm in ("k", "v"):
-                    buf = d[nm]                    # (L, n_pages, ps, kvh, dh)
+                    buf = d[nm]                    # (L, n_pages, kvh, ps, dh)
                     d[nm] = buf.at[:, dst].set(buf[:, src])
                 new[part] = d
             return new
@@ -478,16 +478,19 @@ class RuntimeKernels:
         self._cow[n] = fn
         return fn
 
-    def put(self, tree):
-        """Commit arrays to the serving mesh (replicated). Mixing
+    def put(self, tree, axes=None):
+        """Commit arrays to the serving mesh: replicated, or split by the
+        logical ``axes`` tree (the paged pool over KV heads). Mixing
         mesh-committed params with uncommitted slab buffers makes every
         dispatch re-shard its inputs (~15x per-call overhead on CPU), so
         all runtime state goes through here."""
         if self.ctx is None or self.ctx.mesh is None:
             return jax.tree.map(jnp.asarray, tree)
-        sh = jax.sharding.NamedSharding(self.ctx.mesh,
-                                        jax.sharding.PartitionSpec())
-        return jax.tree.map(lambda x: jax.device_put(x, sh), tree)
+        if axes is None:
+            axes = jax.tree.map(lambda _: jax.sharding.PartitionSpec(), tree)
+        return jax.tree.map(
+            lambda x, ax: jax.device_put(x, self.ctx.sharding(ax, x.shape)),
+            tree, axes)
 
 
 @dataclass
@@ -592,8 +595,10 @@ class DecodeRuntime:
             self.pages_hwm = 0                  # pool high-water (telemetry)
             self._pages_dev = None              # mesh-committed copy
             self._pages_dirty = True
-            self.cache = self.kernels.put(MA.init_paged_cache(
-                self.kernels.cfg, rows, self.alloc.n_pages, rcfg.page_size))
+            self.cache = self.kernels.put(
+                MA.init_paged_cache(self.kernels.cfg, rows,
+                                    self.alloc.n_pages, rcfg.page_size),
+                MA.paged_cache_axes(self.kernels.cfg))
         else:
             self.cache = self.kernels.put(MA.init_slab_cache(
                 self.kernels.cfg, rows, rcfg.capacity))

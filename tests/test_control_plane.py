@@ -293,3 +293,30 @@ def test_e2e_churn_zero_request_loss(tmp_path):
     # the moved replica carried its counters across the reschedule
     moved = [r for r in eng.cluster.pods_of("ersap") if r.restored_from]
     assert moved and moved[0].restored_state is not None
+
+
+def test_elastic_tp_beyond_devices_raises():
+    """A tensor-parallel degree larger than the platform's device count is
+    an error at once, not a one-replica clamp that fails later in
+    make_mesh."""
+    cfg = get_config("qwen2-7b").reduced()
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match=f"tp={n + 1} needs"):
+        ElasticServing(cfg, tp=n + 1).max_replicas()
+    assert ElasticServing(cfg, tp=1).max_replicas() == n
+
+
+def test_elastic_seeded_build_draws_params_on_device():
+    """``build(seed=...)`` draws the parameters inside one jitted init,
+    sharded as param_axes says: the same values as an eager init from the
+    same key, already committed to the serving mesh."""
+    cfg = get_config("qwen2-7b").reduced()
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
+    eager = MA.get_module(cfg).init(jax.random.PRNGKey(0), cfg)
+    for got, want in zip(jax.tree.leaves(serving.params),
+                         jax.tree.leaves(eager)):
+        assert got.sharding.mesh is serving.mesh
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+    with pytest.raises(RuntimeError, match="no params yet"):
+        ElasticServing(cfg, tp=1).build(1)

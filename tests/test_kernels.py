@@ -208,8 +208,8 @@ def test_decode_attention_per_row_pos_kernel_parity():
 def _paged_case(seed, B=3, Hq=4, Hkv=2, dh=16, ps=8, P=4, n_pages=16):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(B, Hq, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, Hkv, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, Hkv, ps, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, Hkv, ps, dh)), jnp.float32)
     lengths = rng.integers(1, P * ps + 1, B)
     pages = np.zeros((B, P), np.int32)
     nxt = 1                      # page 0 stays the null page
@@ -221,6 +221,13 @@ def _paged_case(seed, B=3, Hq=4, Hkv=2, dh=16, ps=8, P=4, n_pages=16):
     return q, kp, vp, jnp.asarray(pages), jnp.asarray(lengths, jnp.int32)
 
 
+def _gather(pool, pages):
+    """Head-major pool pages -> each row's dense (B, P*ps, Hkv, dh) cache."""
+    B, P = pages.shape
+    g = pool[pages].transpose(0, 1, 3, 2, 4)            # (B, P, ps, Hkv, dh)
+    return g.reshape(B, P * pool.shape[2], pool.shape[1], pool.shape[3])
+
+
 @pytest.mark.parametrize("window,chunk", [(None, None), (11, None),
                                           (None, 16)])
 def test_paged_decode_attention_kernel_vs_gathered_ref(window, chunk):
@@ -230,10 +237,7 @@ def test_paged_decode_attention_kernel_vs_gathered_ref(window, chunk):
     out = paged_decode_attention_kernel(q, kp, vp, pages, lengths,
                                         window=window, chunk=chunk,
                                         interpret=True)
-    B, P = pages.shape
-    ps = kp.shape[1]
-    kb = kp[pages].reshape(B, P * ps, *kp.shape[2:])
-    vb = vp[pages].reshape(B, P * ps, *vp.shape[2:])
+    kb, vb = _gather(kp, pages), _gather(vp, pages)
     ref = R.decode_attention_ref(q, kb, vb, lengths=lengths, window=window,
                                  chunk=chunk)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
@@ -250,8 +254,7 @@ def test_paged_decode_attention_property(data):
                                             n_pages=B * P + 2)
     out = paged_decode_attention_kernel(q, kp, vp, pages, lengths,
                                         interpret=True)
-    kb = kp[pages].reshape(B, P * ps, *kp.shape[2:])
-    vb = vp[pages].reshape(B, P * ps, *vp.shape[2:])
+    kb, vb = _gather(kp, pages), _gather(vp, pages)
     ref = R.decode_attention_ref(q, kb, vb, lengths=lengths)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
@@ -278,6 +281,31 @@ def test_kernel_mode_routes_paged_dispatch():
     np.testing.assert_allclose(o_pal, o_jnp, atol=2e-5, rtol=2e-5)
     with pytest.raises(ValueError):
         ops.set_kernel_mode("cuda")
+
+
+@pytest.mark.parametrize("width", [1, 3])
+@pytest.mark.parametrize("softcap", [0.0, 5.0])
+def test_paged_ops_pallas_matches_jnp(width, softcap):
+    """Pallas mode runs the kernel for every paged dispatch — soft-capped
+    logits included, with no quiet drop to jnp — and matches the jnp
+    path: the 1-token decode and the W-token window (spec verify / tail
+    prefill) over the same pool."""
+    q, kp, vp, pages, lengths = _paged_case(5)
+    qw = jnp.stack([q * (1.0 + 0.1 * w) for w in range(width)], axis=1)
+    pos = jnp.maximum(lengths - width, 0)
+    kw = dict(kv_bucket=32, page_size=8, softcap=softcap)
+    outs = {}
+    try:
+        for mode in ("jnp", "pallas"):
+            ops.set_kernel_mode(mode)
+            outs[mode] = (ops.window_attention_paged(qw, kp, vp, pages, pos,
+                                                     **kw) if width > 1 else
+                          ops.decode_attention_paged(qw, kp, vp, pages,
+                                                     lengths, **kw))
+    finally:
+        ops.set_kernel_mode(None)
+    np.testing.assert_allclose(outs["pallas"], outs["jnp"], atol=2e-5,
+                               rtol=2e-5)
 
 
 def test_decode_step_paged_pallas_vs_jnp():

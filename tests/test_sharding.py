@@ -3,11 +3,11 @@ import os
 import subprocess
 import sys
 
-SUB_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"}
-# without this, jax spends minutes probing for accelerator platforms in
-# the stripped subprocess environment
-if "JAX_PLATFORMS" in os.environ:
-    SUB_ENV["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# children stay on the CPU: a TPU belongs to one process, and without a
+# platform jax spends minutes probing for accelerators
+SUB_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+           "HOME": os.environ.get("HOME", ROOT), "JAX_PLATFORMS": "cpu"}
 
 import jax
 import jax.numpy as jnp
@@ -132,18 +132,17 @@ assert d < 1e-3, d
 print("OK")
 """
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=dict(SUB_ENV), cwd="/root/repo",
-                       timeout=420)
+                       text=True, env=dict(SUB_ENV), cwd=ROOT, timeout=420)
     assert "OK" in r.stdout, r.stdout + r.stderr
 
 
 @pytest.mark.slow
-def test_dryrun_one_cell_compiles_on_512_devices():
+def test_dryrun_one_cell_compiles_on_512_devices(tmp_path):
     """The dry-run entrypoint itself (512 fake devices, production mesh)."""
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "hymba-1.5b",
-         "--shape", "long_500k", "--mesh", "pod", "--out", "/tmp/dryrun_test"],
+         "--shape", "long_500k", "--mesh", "pod", "--out", str(tmp_path)],
         capture_output=True, text=True,
-        env=dict(SUB_ENV), cwd="/root/repo", timeout=420)
+        env=dict(SUB_ENV), cwd=ROOT, timeout=420)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "ok" in r.stdout

@@ -9,12 +9,11 @@ import pytest
 
 import os
 
-ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"}
-# without this, jax spends minutes probing for accelerator platforms in
-# the stripped subprocess environment
-if "JAX_PLATFORMS" in os.environ:
-    ENV["JAX_PLATFORMS"] = os.environ["JAX_PLATFORMS"]
-CWD = "/root/repo"
+CWD = str(pathlib.Path(__file__).resolve().parents[1])
+# children stay on the CPU: a TPU belongs to one process, and without a
+# platform jax spends minutes probing for accelerators
+ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+       "HOME": os.environ.get("HOME", CWD), "JAX_PLATFORMS": "cpu"}
 
 
 def run(args, timeout=560):
@@ -69,7 +68,8 @@ def test_walltime_drain_checkpoints_and_exits(tmp_path):
 
 @pytest.mark.slow
 def test_serve_e2e_twin_scales(tmp_path):
-    r = run(["repro.launch.serve", "--arch", "qwen2-7b", "--devices", "8",
+    r = run(["repro.launch.serve", "--arch", "qwen2-7b", "--reduced",
+             "--devices", "8",
              "--tp", "2", "--nodes", "4", "--ticks", "40",
              "--kernel-mode", "auto"], timeout=560)
     assert r.returncode == 0, r.stdout + r.stderr
