@@ -327,9 +327,7 @@ def bench_federation_churn():
     from repro.streaming.engine import StreamEngine
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
 
     fe = FrontEnd()
     jcs = CentralService(fe)
@@ -410,9 +408,7 @@ def bench_priority_spike():
     from repro.streaming.engine import StreamEngine
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
 
     cluster = Cluster()
     for i in range(4):
@@ -529,9 +525,7 @@ def bench_chaos_soak():
     from repro.streaming.runtime import RuntimeConfig
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
 
     dt = 10.0
     ticks = 36 if FAST else 56
@@ -692,9 +686,7 @@ def bench_overload_brownout():
     from repro.streaming.runtime import RuntimeConfig
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
 
     dt = 10.0
     ticks = 26 if FAST else 40
@@ -1045,9 +1037,7 @@ def bench_serving_throughput():
     from repro.streaming.engine import StreamEngine
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     n_req = 24 if FAST else 96
 
     def request_set():
@@ -1132,9 +1122,7 @@ def bench_paged_decode():
     from repro.streaming.runtime import DecodeRuntime, RuntimeConfig
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     n_req = 32 if FAST else 96
 
     def request_set():
@@ -1246,9 +1234,7 @@ def bench_prefix_reuse():
     from repro.streaming.runtime import DecodeRuntime, RuntimeConfig
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     n_req = 40 if FAST else 80
     plen, n_groups = 64, 4
     plen_a = 128                  # admission-phase prompts (prefill-heavy)
@@ -1490,9 +1476,7 @@ def bench_observability_overhead():
     from repro.streaming.engine import StreamEngine
 
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     n_req = 24 if FAST else 96
 
     def request_set():

@@ -70,17 +70,14 @@ class ElasticServing:
         self._compiled[key] = entry
         return entry
 
-    def build(self, replicas: int, host_params=None, now: float = 0.0,
+    def build(self, replicas: int, now: float = 0.0,
               seed: Optional[int] = None):
-        """(Re)build at ``replicas`` data-parallel replicas. Parameters come
-        from ``host_params``, or are drawn on the devices from ``seed``
-        (each device builds only its own shard), or else are the current
-        ones, moved onto the new mesh."""
+        """(Re)build at ``replicas`` data-parallel replicas. Parameters are
+        drawn on the devices from ``seed`` (each device builds only its own
+        shard), or else are the current ones, moved onto the new mesh."""
         replicas = min(max(replicas, 1), self.max_replicas())
         mesh, ctx, prefill_fn, decode_fn, psh = self._lowered(replicas)
-        if host_params is not None:
-            params = jax.tree.map(jax.device_put, host_params, psh)
-        elif seed is not None:
+        if seed is not None:
             mod = MA.get_module(self.cfg)
             init = functools.partial(mod.init, cfg=self.cfg)
             params = jax.jit(init, out_shardings=psh)(
@@ -88,8 +85,7 @@ class ElasticServing:
         elif self.params is not None:
             params = jax.tree.map(jax.device_put, self.params, psh)
         else:
-            raise RuntimeError("no params yet — build with host_params or "
-                               "seed")
+            raise RuntimeError("no params yet — build with a seed")
         self.prefill_fn, self.decode_fn = prefill_fn, decode_fn
         old = self.replicas
         self.mesh, self.ctx, self.params = mesh, ctx, params

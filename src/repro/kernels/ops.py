@@ -100,18 +100,23 @@ def _paged_kernel_call(kernel, q, k_pool, v_pool, pages, lengths, ctx,
     GSPMD cannot partition a ``pallas_call``, so on a mesh the kernel runs
     under ``shard_map``: the pool's KV-head axis (and the query heads of
     each KV group with it) is split the way the pool is committed
-    (``model_api.paged_cache_axes``), everything else is replicated. Each
-    device then attends over its own KV heads; no collective is needed."""
+    (``model_api.paged_cache_axes``), and the batch rows (queries, page
+    table, lengths) the way decode activations are (``"batch"``). Each
+    device then attends its own rows over its own KV heads; no collective
+    is needed."""
     call = functools.partial(kernel, interpret=_interpret(), **kw)
     if ctx is None or ctx.mesh is None:
         return call(q, k_pool, v_pool, pages, lengths)
     from jax.sharding import PartitionSpec as P
-    spec = ctx.spec((None, "kv_heads"), k_pool.shape)
-    ax = spec[1] if len(spec) > 1 else None
-    q_spec = P(*([None] * (q.ndim - 2)), ax, None)
-    pool_spec = P(None, ax)
+    pool = ctx.spec((None, "kv_heads"), k_pool.shape)
+    kv_ax = pool[1] if len(pool) > 1 else None
+    rows = ctx.spec(("batch",), q.shape[:1])
+    b_ax = rows[0] if len(rows) else None
+    q_spec = P(b_ax, *([None] * (q.ndim - 3)), kv_ax, None)
+    pool_spec = P(None, kv_ax)
     return jax.shard_map(call, mesh=ctx.mesh,
-                         in_specs=(q_spec, pool_spec, pool_spec, P(), P()),
+                         in_specs=(q_spec, pool_spec, pool_spec, P(b_ax),
+                                   P(b_ax)),
                          out_specs=q_spec, check_vma=False)(
         q, k_pool, v_pool, pages, lengths)
 

@@ -203,13 +203,11 @@ def init_paged_cache(cfg: ArchConfig, rows: int, n_pages: int,
     Which pages a row owns lives host-side (the runtime's page table /
     allocator); physical page 0 is reserved as the null page. HBM scales
     with the pool, not with rows x capacity."""
-    n_moe = 0 if cfg.moe is None else cfg.n_layers - cfg.moe.first_k_dense
-    shape = lambda n: (n, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
-    cache = {"pos": jnp.zeros((rows,), jnp.int32)}
-    for part, n in (("dense", cfg.n_layers - n_moe), ("moe", n_moe)):
-        if n:
-            cache[part] = {"k": jnp.zeros(shape(n), cfg.jdtype),
-                           "v": jnp.zeros(shape(n), cfg.jdtype)}
+    # the module's slab cache, one "row" per page: (L, n_pages, ps, kvh, dh)
+    slab = get_module(cfg).init_cache(cfg, n_pages, page_size)
+    cache = {part: {nm: kv.swapaxes(2, 3) for nm, kv in slab[part].items()}
+             for part in ("dense", "moe") if part in slab}
+    cache["pos"] = jnp.zeros((rows,), jnp.int32)
     return cache
 
 
@@ -267,14 +265,15 @@ def fused_decode(params, tok, cache, active, remaining, cfg: ArchConfig,
     only): the page table is constant across the fused block — the host
     pre-allocates pages covering every row's position through the final
     step — and ``kv_bucket`` must cover max(pos) + steps.
-    Returns (tok, cache, active, remaining, tokens (steps, S))."""
+    Returns (tok, cache, active, remaining, tokens (steps, S), the last
+    step's logits (S, V) in float32)."""
     mod = get_module(cfg)
     kw = {} if pages is None else {"pages": pages, "kv_bucket": kv_bucket}
     if block_skip is not None:       # 0 = force the plain full-width path
         kw["block_skip"] = block_skip
 
     def step(carry, _):
-        tok, cache, active, remaining = carry
+        tok, cache, active, remaining, _ = carry
         pos0 = cache["pos"]
         logits, cache = mod.decode_step(params, tok, cache, cfg, ctx, **kw)
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
@@ -282,11 +281,13 @@ def fused_decode(params, tok, cache, active, remaining, cfg: ArchConfig,
         cache["pos"] = jnp.where(active, cache["pos"], pos0)
         remaining = remaining - active.astype(jnp.int32)
         active = active & (remaining > 0)
-        return (tok, cache, active, remaining), nxt[:, 0]
+        return ((tok, cache, active, remaining, logits.astype(jnp.float32)),
+                nxt[:, 0])
 
-    (tok, cache, active, remaining), toks = jax.lax.scan(
-        step, (tok, cache, active, remaining), None, length=steps)
-    return tok, cache, active, remaining, toks
+    last = jnp.zeros((tok.shape[0], cfg.vocab), jnp.float32)
+    (tok, cache, active, remaining, last), toks = jax.lax.scan(
+        step, (tok, cache, active, remaining, last), None, length=steps)
+    return tok, cache, active, remaining, toks, last
 
 
 def decode_window(params, tokens, cache, cfg: ArchConfig, ctx=None, *,

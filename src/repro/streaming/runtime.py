@@ -369,7 +369,7 @@ class RuntimeKernels:
                 # tail steps run plain on the dense slab (a freshly
                 # admitted bucket usually fills a good share of capacity;
                 # skipping is the decode blocks' per-dispatch decision)
-                tok, cache, active, remaining, _ = MA.fused_decode(
+                tok, cache, active, remaining, _, _ = MA.fused_decode(
                     params, tok, cache, active, remaining, cfg, ctx,
                     steps=tail, pages=pages,
                     kv_bucket=kvb if rcfg.paged else None,
@@ -1225,7 +1225,7 @@ class DecodeRuntime:
                              steps=steps,
                              rids=tuple(self.slots[i].req.rid
                                         for i in before))
-        self.tok, self.cache, self.active, self.remaining, toks = fn(
+        self.tok, self.cache, self.active, self.remaining, toks, _ = fn(
             self.params, self.tok, self.cache, self.active, self.remaining,
             **kw)
         self.steps_dispatched += 1

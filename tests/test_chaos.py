@@ -13,7 +13,6 @@ completion.
 """
 import json
 
-import jax
 import numpy as np
 import pytest
 
@@ -27,7 +26,6 @@ from repro.core.elastic import ElasticServing
 from repro.core.jrm import SliceSpec, start_vk
 from repro.core.scheduler import Scheduler, _jitter_u
 from repro.core.state_machine import Container, Pod
-from repro.models import model_api as MA
 from repro.streaming.engine import StreamEngine
 from repro.streaming.runtime import RuntimeConfig
 
@@ -365,9 +363,7 @@ def test_auditor_catches_duplicate_completion_and_double_booking():
 
 def _mk_engine(walltimes, service_rate=6.0, chips=2):
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     nodes = [start_vk(f"n{i}", walltime=w, now=0.0,
                       slice_spec=SliceSpec(chips=chips))
              for i, w in enumerate(walltimes)]

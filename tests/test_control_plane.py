@@ -212,9 +212,7 @@ def test_drain_checkpoint_evict_reschedule_restores_state(tmp_path):
 
 def _engine(nodes_walltimes, service_rate=4.0, replicas=1, chips=4):
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(replicas, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(replicas, seed=0)
     nodes = [start_vk(f"n{i}", walltime=w, now=0.0,
                       slice_spec=SliceSpec(chips=chips))
              for i, w in enumerate(nodes_walltimes)]

@@ -3,8 +3,6 @@ filter/score stages (selector, anti-affinity, data locality,
 latency-weighted spreading), batch drain of a whole pilot allocation with
 zero request loss, and JCS proactive re-provisioning on walltime
 shortfall."""
-import jax
-import numpy as np
 import pytest
 
 from repro.configs.base import get_config
@@ -16,7 +14,6 @@ from repro.core.jfe import FrontEnd
 from repro.core.jrm import SliceSpec, start_vk
 from repro.core.scheduler import Scheduler, SiteTopology
 from repro.core.state_machine import Container, Pod
-from repro.models import model_api as MA
 from repro.streaming.engine import StreamEngine
 
 TOL = [{"key": "virtual-kubelet.io/provider", "value": "mock"}]
@@ -304,9 +301,7 @@ def test_drain_allocation_is_one_wave():
 @pytest.fixture(scope="module")
 def serving():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    return ElasticServing(cfg, tp=1).build(1, host_params=host)
+    return ElasticServing(cfg, tp=1).build(1, seed=0)
 
 
 def test_site_kill_zero_request_loss(serving, tmp_path):

@@ -1,5 +1,4 @@
 """Monitoring stack (§4.6) + elastic serving + streaming engine."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,7 +8,6 @@ from repro.core.elastic import ElasticServing
 from repro.core.jrm import SliceSpec, start_vk
 from repro.core.metrics import (Endpoint, Prometheus, Registry, Service,
                                 ServiceMonitor)
-from repro.models import model_api as MA
 from repro.streaming.engine import StreamEngine
 
 
@@ -52,10 +50,8 @@ def test_service_label_selection():
 
 def test_elastic_scale_preserves_outputs():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
     s = ElasticServing(cfg, tp=1)
-    s.build(1, host_params=host)
+    s.build(1, seed=0)
     toks = np.random.default_rng(0).integers(0, cfg.vocab, (2, 16)).astype(np.int32)
     l1, _ = s.prefill_fn(s.params, toks)
     s.scale_to(1)           # no-op
@@ -71,9 +67,7 @@ def test_elastic_scale_preserves_outputs():
 
 def test_stream_engine_serves_and_scales():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     nodes = [start_vk(f"n{i}", now=0.0, slice_spec=SliceSpec(chips=4))
              for i in range(2)]
     eng = StreamEngine(cfg, serving, nodes, service_rate=2.0, max_batch=4)
@@ -96,9 +90,7 @@ def test_stream_engine_serves_and_scales():
 def test_engine_real_model_tokens():
     """The engine runs actual prefill+decode: token counts add up."""
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     nodes = [start_vk("n0", now=0.0, slice_spec=SliceSpec(chips=4))]
     eng = StreamEngine(cfg, serving, nodes, service_rate=1.0, max_batch=2)
     eng.deploy(0.0)

@@ -11,8 +11,6 @@ import math
 import pathlib
 import sys
 
-import jax
-import numpy as np
 import pytest
 
 from repro.configs.base import get_config
@@ -25,7 +23,6 @@ from repro.core.observability import (FlightRecorder, SLOConfig,
                                       render_exposition)
 from repro.core.tracing import NULL_TRACER, Tracer
 from repro.data.pipeline import Request, RequestSource
-from repro.models import model_api as MA
 from repro.streaming.engine import StreamEngine
 from repro.streaming.runtime import RuntimeConfig
 
@@ -275,9 +272,7 @@ def test_metriclint_inventory_is_clean():
 
 def _mk_engine(n_nodes=2, chips=2):
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     nodes = [start_vk(f"n{i}", now=0.0, slice_spec=SliceSpec(chips=chips))
              for i in range(n_nodes)]
     return StreamEngine(cfg, serving, nodes, service_rate=100.0,

@@ -12,7 +12,6 @@ degraded service caps output length without changing token *content*
 (prefix of the unloaded oracle's stream); ring caps truncate with
 explicit markers; drain_site pays the topology's transfer window and the
 engine serves degraded for its duration."""
-import jax
 import numpy as np
 import pytest
 
@@ -24,7 +23,6 @@ from repro.core.elastic import ElasticServing
 from repro.core.jrm import SliceSpec, start_vk
 from repro.core.scheduler import Scheduler, SiteTopology
 from repro.data.pipeline import Request, RequestSource
-from repro.models import model_api as MA
 from repro.streaming.engine import StreamEngine
 from repro.streaming.runtime import DecodeRuntime, RuntimeConfig
 
@@ -32,9 +30,7 @@ from repro.streaming.runtime import DecodeRuntime, RuntimeConfig
 @pytest.fixture(scope="module")
 def serving():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    return ElasticServing(cfg, tp=1).build(1, host_params=host)
+    return ElasticServing(cfg, tp=1).build(1, seed=0)
 
 
 def mk_engine(serving, n_nodes=1, **kw):

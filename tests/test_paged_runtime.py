@@ -3,7 +3,6 @@ identity, pool-exhaustion backpressure, fragmentation-free reuse across
 mid-stream retirement, page-table checkpoint round-trip through the
 §4.5.4 drain loop, trace bounds under randomized shapes, and the
 equal-HBM wide-batch configuration."""
-import jax
 import numpy as np
 import pytest
 
@@ -11,7 +10,6 @@ from repro.checkpoint import checkpointer
 from repro.configs.base import get_config
 from repro.core.elastic import ElasticServing
 from repro.data.pipeline import Request
-from repro.models import model_api as MA
 from repro.streaming.runtime import (DecodeRuntime, PageAllocator,
                                      RuntimeConfig)
 
@@ -19,9 +17,7 @@ from repro.streaming.runtime import (DecodeRuntime, PageAllocator,
 @pytest.fixture(scope="module")
 def serving():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    return ElasticServing(cfg, tp=1).build(1, host_params=host)
+    return ElasticServing(cfg, tp=1).build(1, seed=0)
 
 
 def mk_runtime(serving, rcfg, **kw):

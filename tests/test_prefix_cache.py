@@ -5,7 +5,6 @@ refcount-books interleavings, drain -> restore sharing survival, and
 k-token speculative decode equivalence. Every identity test compares
 against the prefix-off (or spec-off) oracle on the same requests — the
 sharing layer is an admission optimization, never a model change."""
-import jax
 import numpy as np
 import pytest
 
@@ -13,7 +12,6 @@ from repro.checkpoint import checkpointer
 from repro.configs.base import get_config
 from repro.core.elastic import ElasticServing
 from repro.data.pipeline import Request
-from repro.models import model_api as MA
 from repro.streaming.runtime import (DecodeRuntime, PageAllocator,
                                      RuntimeConfig)
 
@@ -21,9 +19,7 @@ from repro.streaming.runtime import (DecodeRuntime, PageAllocator,
 @pytest.fixture(scope="module")
 def serving():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    return ElasticServing(cfg, tp=1).build(1, host_params=host)
+    return ElasticServing(cfg, tp=1).build(1, seed=0)
 
 
 def mk_runtime(serving, rcfg, **kw):

@@ -7,8 +7,6 @@ or non-preemptible pods; quota books balance (used + free == capacity,
 per-owner sums match the node truth) after preempt -> requeue ->
 reschedule; priority writes round-trip through a full drain; a
 mixed-tenant pressure spike loses zero serving requests."""
-import jax
-import numpy as np
 import pytest
 
 from repro.configs.base import get_config
@@ -22,7 +20,6 @@ from repro.core.hpa import HPA, HPAConfig, PressureSignals
 from repro.core.jrm import SliceSpec, start_vk
 from repro.core.scheduler import Scheduler
 from repro.core.state_machine import Container, Pod
-from repro.models import model_api as MA
 from repro.streaming.engine import StreamEngine
 
 TOL = [{"key": "virtual-kubelet.io/provider", "value": "mock"}]
@@ -404,9 +401,7 @@ def test_mixed_tenant_spike_zero_serving_loss(tmp_path):
     batch (checkpointed), de-escalation lets batch resume — and every
     serving request that arrived is served exactly once."""
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    serving = ElasticServing(cfg, tp=1).build(1, host_params=host)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
     cluster = Cluster()
     for i in range(2):
         cluster.register_node(start_vk(f"n{i}", now=0.0,

@@ -2,7 +2,6 @@
 compilation (bounded jit traces), fused scan decode, fractional tick
 budgets, compile-cache reuse on rescale, and slot-table checkpoint
 round-trips through the drain -> reschedule loop."""
-import jax
 import numpy as np
 import pytest
 
@@ -20,9 +19,7 @@ from repro.streaming.runtime import (DecodeRuntime, RuntimeConfig,
 @pytest.fixture(scope="module")
 def serving():
     cfg = get_config("qwen2-7b").reduced()
-    mod = MA.get_module(cfg)
-    host = jax.tree.map(np.asarray, mod.init(jax.random.PRNGKey(0), cfg))
-    return ElasticServing(cfg, tp=1).build(1, host_params=host)
+    return ElasticServing(cfg, tp=1).build(1, seed=0)
 
 
 def mk_runtime(serving, rcfg=None, **kw):

@@ -81,6 +81,38 @@ def test_serve_e2e_twin_scales(tmp_path):
     assert ", 1, 2)" in r.stdout
 
 
+SMOKE_LOGITS = """
+import json, sys
+sys.path.insert(0, ".")
+import chip_smoke
+from repro.launch import serve
+eng = serve.main(["--arch", "qwen2-7b", "--reduced", "--nodes", "1",
+                  "--paged", "--vary-shapes", "--ticks", "2",
+                  "--lam-scale", "0.006"])
+print(json.dumps(chip_smoke.logits_phase(eng.serving, eng.runtime_cfg)))
+"""
+
+
+def test_smoke_logits_check_separates_sound_from_broken():
+    """chip_smoke's logits check at the reduced size: the served runtime's
+    last-step logits match the uncached forward, and both broken controls
+    (shifted page table, zeroed KV head) read far above the tolerance."""
+    import json
+    sys.path.insert(0, CWD)
+    from chip_smoke import LOGIT_TOL, N_PROMPTS
+    r = subprocess.run([sys.executable, "-c", SMOKE_LOGITS],
+                       capture_output=True, text=True, env=ENV, cwd=CWD,
+                       timeout=300)
+    assert r.returncode == 0, r.stdout + r.stderr
+    errs = json.loads(r.stdout.strip().splitlines()[-1])
+    # float32 at the reduced size: the sound paths agree to rounding
+    for k in ("pallas_vs_ref", "jnp_vs_ref", "pallas_vs_jnp"):
+        assert errs[k] <= LOGIT_TOL / 100, errs
+    assert errs["rows_same_tokens"] == N_PROMPTS
+    for k in ("control_shifted_pages", "control_zeroed_head"):
+        assert errs[k] > 2 * LOGIT_TOL, errs
+
+
 def test_slurm_asset_generation(tmp_path):
     from repro.launch.slurm import generate
     files = generate(tmp_path, nodes=40, walltime="03:00:00")
@@ -93,3 +125,31 @@ def test_slurm_asset_generation(tmp_path):
     assert 'JIRIAF_WALLTIME="10740"' in node
     assert "ssh -NfL $APISERVER_PORT" in node
     assert "ssh -NfR $KUBELET_PORT" in node
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(monkeypatch, tmp_path, from_env):
+    """Entry points keep compiled programs in ``$JAX_COMPILATION_CACHE_DIR``
+    when it is set (and set no path of their own), else in the checkout's
+    fixed ``.jax_cache``; every program is written, however fast."""
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in names}
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = enable_compile_cache()
+        if from_env:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == saved[names[0]]
+        else:
+            assert path == str(pathlib.Path(CWD) / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

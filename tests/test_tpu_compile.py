@@ -89,14 +89,18 @@ def test_flash_prefill_kernel_compiles_for_v5e(one_chip):
     _assert_kernel(jax.jit(flash_attention).lower(q, kv, kv).compile())
 
 
-def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch):
-    """tp = 4: the pool split over KV heads (one per chip), the kernel run
-    per shard under shard_map, as the serving runtime commits it."""
-    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+@pytest.mark.parametrize("replicas,tp", [(1, 4), (2, 2)])
+def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch, replicas,
+                                              tp):
+    """(replicas, tp) serving meshes: the pool split over KV heads, the
+    batch rows over replicas, the kernel run per shard under shard_map, as
+    the serving runtime commits them."""
+    mesh = Mesh(np.array(topo.devices).reshape(replicas, tp),
+                ("data", "model"))
     ctx = ShardCtx(mesh)
-    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("data"))
     pool_sh = NamedSharding(mesh, P(None, "model"))
-    args = _paged_args(rep, (B, 1, HQ, DH), pool_sh)
+    args = _paged_args(rows, (B, 1, HQ, DH), pool_sh)
     # the ops layer asks the process's own backend (the CPU here) whether
     # to interpret; this compile is for the described TPU
     monkeypatch.setattr(ops, "_interpret", lambda: False)
@@ -109,5 +113,6 @@ def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch):
 
     compiled = jax.jit(step).lower(*args).compile()
     _assert_kernel(compiled)
-    # each chip attends over its own KV head: no gather of the pool
+    # each chip attends its own rows over its own KV heads: nothing is
+    # gathered, neither the pool nor the queries
     assert "all-gather" not in compiled.as_text()
