@@ -1462,7 +1462,7 @@ def bench_observability_overhead():
     checks + TickProfiler) vs stock, same engine configuration and
     manufactured request set. Tracing must be cheap enough to leave on:
     ``--check`` floors ``tokens_ratio`` (on/off) at 0.95, i.e. <5%
-    throughput cost. The on-path report carries the pump/tick phase
+    throughput cost. The on-path report carries the tick phase
     breakdown so BENCH_serving.json records where the tick goes."""
     import jax
     from repro.configs.base import get_config

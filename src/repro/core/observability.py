@@ -1,5 +1,5 @@
 """Unified observability plane: Prometheus text exposition over the
-§4.6 registries, a tick/pump phase profiler, and an SLO flight recorder.
+§4.6 registries, a tick phase profiler, and an SLO flight recorder.
 
 Three pieces (tentpole 2-4 of the observability PR):
 
@@ -9,9 +9,9 @@ Three pieces (tentpole 2-4 of the observability PR):
   histograms. ``serve.py --metrics-out`` dumps it; ``tools/metriclint.py``
   and the obs-smoke CI job parse it back.
 - ``TickProfiler``: cheap phase-timing accumulator for the control-plane
-  tick (nodes reconcile, deployment reconcile, scheduler place, audit)
-  and the runtime ``pump()`` (admit, decode, retire). Surfaced per bench
-  in ``BENCH_*.json`` and by ``serve.py`` at end of run.
+  tick (nodes reconcile, deployment reconcile, scheduler place, audit).
+  Surfaced per bench in ``BENCH_*.json`` and by ``serve.py`` at end of
+  run.
 - ``FlightRecorder``: bounded ring of recent events riding the span
   ring, with burn-rate SLO tracking over a sliding window (LC p99
   latency, shed fraction, restore latency). A threshold breach or an
@@ -126,8 +126,8 @@ class TickProfiler:
     """Phase-timing accumulator (wall-clock, ``time.perf_counter``).
 
     Phases are plain string names; nesting is allowed and simply counts
-    the inner phase inside the outer one (``pump.retire`` runs inside
-    ``pump.admit``/``pump.decode`` — the harvest is part of both)."""
+    the inner phase inside the outer one. The control plane's tick phases
+    use it; the runtime times its host work with ``Tracer.region``."""
 
     def __init__(self):
         self.phases: Dict[str, list] = {}      # name -> [calls, total_s]

@@ -242,8 +242,7 @@ class StreamEngine:
                              record_tokens=self.record_tokens,
                              token_log_cap=self.token_log_cap,
                              name=name, tracer=self.tracer,
-                             metrics=self.registries.get(name),
-                             profiler=self.profiler)
+                             metrics=self.registries.get(name))
 
     def _credit_partial(self, name: str, rt: DecodeRuntime):
         """Credit partial generation of in-flight slots before their
@@ -694,7 +693,7 @@ class StreamEngine:
         source (enqueue spans), QoS machines (brownout/breaker spans),
         control plane + scheduler + lifecycle controller (schedule/
         preempt/checkpoint/drain spans, tick phase profile), and every
-        live runtime (admit/prefill/decode spans, TTFT, pump profile).
+        live runtime (admit spans, host-clock regions, TTFT).
         Safe to call before or after ``deploy``; later-built runtimes
         and planes inherit via ``_make_runtime`` / ``_ensure_plane``."""
         if tracer is not None:
@@ -713,8 +712,6 @@ class StreamEngine:
             rt.name = name
             if tracer is not None:
                 rt.tracer = tracer
-            if profiler is not None:
-                rt.profiler = profiler
 
     def exposition(self) -> str:
         """Prometheus text exposition of the whole metric pipeline: the

@@ -41,7 +41,6 @@ drain is token-identical to an undisturbed run.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -50,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.core.tracing import NULL_REGION
 from repro.data.pipeline import Request
 from repro.models import model_api as MA
 
@@ -568,10 +568,12 @@ class DecodeRuntime:
     # observability plane (all optional; None = zero-cost disabled path).
     # ``name`` is the replica/pod identity stamped on spans; ``sim_now``
     # mirrors the engine clock so runtime-emitted spans carry sim-time.
+    # The tracer's regions (``_region``) time the host work of a step on
+    # the host clock: admit_plan, prefill, first_token, decode,
+    # decode_read, harvest, pages_commit.
     name: str = ""
     tracer: object = None
     metrics: object = None            # per-pod Registry (TTFT histogram)
-    profiler: object = None           # TickProfiler (pump phase timing)
     sim_now: float = 0.0
 
     def __post_init__(self):
@@ -664,12 +666,23 @@ class DecodeRuntime:
         self.peak_slots = self.slots_in_use
         self.peak_pages = self.pages_in_use
 
+    def _region(self, name: str, **attrs):
+        """A region of the tracer's ring on the host clock
+        (``Tracer.region``), or a no-op context without a tracer. Enters
+        to the span, or to ``None``."""
+        if self.tracer is None:
+            return NULL_REGION
+        return self.tracer.region(name, self.sim_now, replica=self.name,
+                                  **attrs)
+
     def _device_pages(self):
         """Mesh-committed page table, refreshed only when the host table
         mutated (admission/retirement) — uncommitted per-dispatch inputs
         would re-shard the whole argument list (see ``RuntimeKernels.put``)."""
         if self._pages_dirty:
-            self._pages_dev = self.kernels.put(jnp.asarray(self.page_table))
+            with self._region("pages_commit"):
+                self._pages_dev = self.kernels.put(
+                    jnp.asarray(self.page_table))
             self._pages_dirty = False
         return self._pages_dev
 
@@ -743,42 +756,56 @@ class DecodeRuntime:
             return []
         done: List[Finished] = []
         while free and self.pending:
-            groups: Dict[tuple, List[Request]] = {}
-            for r in self.pending:
-                lb = MA.pow2_bucket(r.prompt_len, rcfg.min_prompt_bucket,
-                                    rcfg.max_prompt_bucket)
-                # depth-segregated admission: co-schedule rows whose
-                # generation depth shares a pow2 bucket, so one deep row
-                # doesn't pin the block/kv ladder (and the CoW working
-                # set) for a wave of short batch-mates
-                db = MA.pow2_bucket(max(r.max_new, 1), 1, rcfg.max_new_cap)
-                groups.setdefault((lb, db), []).append(r)
-            (lb, _), group = max(groups.items(), key=lambda kv: len(kv[1]))
-            # within the depth bucket, longest-first keeps fused blocks tight
-            group = sorted(group, key=lambda r: -r.max_new)[:len(free)]
-            grants: Dict[int, dict] = {}
-            if self._paged:
-                # all-or-nothing page grant per request; a request the pool
-                # cannot hold right now stays pending until a retirement
-                # frees pages (fits() guarantees it can be held eventually)
-                granted = []
-                wave: Dict[tuple, dict] = {}    # same-wave leader full keys
-                for r in group:
-                    g = self._plan_grant(r, lb, wave)
-                    if g is None:
-                        break
-                    granted.append(r)
-                    grants[id(r)] = g
-                group = granted
-                if not group:
-                    break
-                self.pages_hwm = max(self.pages_hwm, self.alloc.used_pages)
-                self.peak_pages = max(self.peak_pages, self.alloc.used_pages)
-            taken = set(id(r) for r in group)
-            self.pending = [r for r in self.pending if id(r) not in taken]
+            with self._region("admit_plan", pending=len(self.pending),
+                              free=len(free)):
+                planned = self._plan_wave(len(free))
+            if planned is None:
+                break
+            group, lb, grants = planned
             take, free = free[:len(group)], free[len(group):]
             done.extend(self._admit_batch(group, take, lb, grants))
         return done
+
+    def _plan_wave(self, n_free: int):
+        """The next admission wave, taken off ``pending``: the largest
+        (length bucket, depth bucket) group, longest-first, cut to
+        ``n_free`` slots and, when paged, to what the pool grants now.
+        Returns (requests, lb, grants), or None when nothing is granted."""
+        rcfg = self.kernels.rcfg
+        groups: Dict[tuple, List[Request]] = {}
+        for r in self.pending:
+            lb = MA.pow2_bucket(r.prompt_len, rcfg.min_prompt_bucket,
+                                rcfg.max_prompt_bucket)
+            # depth-segregated admission: co-schedule rows whose
+            # generation depth shares a pow2 bucket, so one deep row
+            # doesn't pin the block/kv ladder (and the CoW working
+            # set) for a wave of short batch-mates
+            db = MA.pow2_bucket(max(r.max_new, 1), 1, rcfg.max_new_cap)
+            groups.setdefault((lb, db), []).append(r)
+        (lb, _), group = max(groups.items(), key=lambda kv: len(kv[1]))
+        # within the depth bucket, longest-first keeps fused blocks tight
+        group = sorted(group, key=lambda r: -r.max_new)[:n_free]
+        grants: Dict[int, dict] = {}
+        if self._paged:
+            # all-or-nothing page grant per request; a request the pool
+            # cannot hold right now stays pending until a retirement
+            # frees pages (fits() guarantees it can be held eventually)
+            granted = []
+            wave: Dict[tuple, dict] = {}    # same-wave leader full keys
+            for r in group:
+                g = self._plan_grant(r, lb, wave)
+                if g is None:
+                    break
+                granted.append(r)
+                grants[id(r)] = g
+            group = granted
+            if not group:
+                return None
+            self.pages_hwm = max(self.pages_hwm, self.alloc.used_pages)
+            self.peak_pages = max(self.peak_pages, self.alloc.used_pages)
+        taken = set(id(r) for r in group)
+        self.pending = [r for r in self.pending if id(r) not in taken]
+        return group, lb, grants
 
     # ---------------------------------------------------- prefix interning
     def _lookup_prefix(self, tokens: np.ndarray, lb: int,
@@ -919,11 +946,11 @@ class DecodeRuntime:
         return tok
 
     def _note_admission(self, reqs: List[Request],
-                        kind_of: Dict[int, str], lb: int) -> None:
-        """Observability tail of an admission wave: per-rid ``admit``
-        spans (with the grant kind), one block-level ``prefill`` span,
-        and the TTFT histogram (sim-time from arrival to first token,
-        which admission produces)."""
+                        grants: Dict[int, dict], lb: int) -> None:
+        """Observability head of an admission wave, at dispatch start:
+        per-rid ``admit`` spans (with the grant kind) and the TTFT
+        histogram (sim-time from arrival to first token, which admission
+        produces)."""
         if self.metrics is not None:
             h = self.metrics.histogram("ersap_ttft_s")
             for r in reqs:
@@ -932,18 +959,48 @@ class DecodeRuntime:
             return
         for r in reqs:
             self.tracer.span("admit", self.sim_now, rid=r.rid,
-                             kind=kind_of.get(id(r), "miss"),
+                             kind=grants.get(id(r), {}).get("kind", "miss"),
                              replica=self.name, lb=lb)
-        self.tracer.span("prefill", self.sim_now, replica=self.name,
-                         lb=lb, rids=tuple(r.rid for r in reqs))
 
     def _admit_batch(self, reqs: List[Request], slot_idx: List[int],
                      lb: int, grants: Dict[int, dict]) -> List[Finished]:
         rcfg = self.kernels.rcfg
+        self._note_admission(reqs, grants, lb)
+        rids = tuple(r.rid for r in reqs) if self.tracer is not None else ()
         if (self._paged and rcfg.prefix_cache
                 and any(grants[id(r)]["kind"] != "miss" for r in reqs)):
-            return self._admit_batch_prefix(reqs, slot_idx, lb, grants)
+            # the wave's dispatches read their first tokens as they go
+            with self._region("prefill", lb=lb, rids=rids):
+                return self._admit_batch_prefix(reqs, slot_idx, lb, grants)
         bb = MA.pow2_bucket(len(reqs), 1, rcfg.max_batch)
+        with self._region("prefill", bb=bb, lb=lb, rids=rids):
+            first = self._prefill(reqs, slot_idx, lb, grants, bb)
+        if rcfg.prefix_cache or rcfg.spec_decode or self.record_tokens:
+            # prefill argmaxes, (bb,): the wave's first tokens on the host
+            with self._region("first_token", rids=rids):
+                first = np.asarray(first)
+        for j, (r, i) in enumerate(zip(reqs, slot_idx)):
+            g = grants.get(id(r), {})
+            s = _Slot(req=r, remaining=int(r.max_new), lb=lb,
+                      pages=tuple(g.get("pages", ())),
+                      reserve=g.get("reserve"))
+            if rcfg.spec_decode:
+                self._spec_init(s, int(first[j]))
+            self.slots[i] = s
+            if rcfg.prefix_cache:
+                self._register_intern(self.content[r.rid], s.pages,
+                                      int(first[j]), lb)
+            if self.record_tokens:               # first token (prefill argmax)
+                self._log_tokens(r.rid, [int(first[j])])
+        self.peak_slots = max(self.peak_slots, self.slots_in_use)
+        # the fused tail advanced every live row (old and new) tail steps
+        return self._harvest(rcfg.admit_tail)
+
+    def _prefill(self, reqs: List[Request], slot_idx: List[int], lb: int,
+                 grants: Dict[int, dict], bb: int):
+        """Publish the wave's page grants and dispatch its ``(bb, lb)``
+        prefill; returns the device array of its first tokens."""
+        rcfg = self.kernels.rcfg
         n_pad = bb - len(reqs)
         # synthetic workload: the prompt is per-request noise from the
         # content store; right-pad to the length bucket and the pad joins
@@ -987,26 +1044,7 @@ class DecodeRuntime:
             self.cache, self.tok, self.active, self.remaining, first = fn(
                 self.params, tokens, self.cache, self.tok,
                 self.active, self.remaining, idx, max_new)
-        if rcfg.prefix_cache or rcfg.spec_decode or self.record_tokens:
-            first = np.asarray(first)            # (bb,) prefill argmaxes
-        for j, (r, i) in enumerate(zip(reqs, slot_idx)):
-            g = grants.get(id(r), {})
-            s = _Slot(req=r, remaining=int(r.max_new), lb=lb,
-                      pages=tuple(g.get("pages", ())),
-                      reserve=g.get("reserve"))
-            if rcfg.spec_decode:
-                self._spec_init(s, int(first[j]))
-            self.slots[i] = s
-            if rcfg.prefix_cache:
-                self._register_intern(self.content[r.rid], s.pages,
-                                      int(first[j]), lb)
-            if self.record_tokens:               # first token (prefill argmax)
-                self._log_tokens(r.rid, [int(first[j])])
-        self.peak_slots = max(self.peak_slots, self.slots_in_use)
-        self._note_admission(reqs, {id(r): grants.get(id(r), {}).get(
-            "kind", "miss") for r in reqs}, lb)
-        # the fused tail advanced every live row (old and new) tail steps
-        return self._harvest(rcfg.admit_tail)
+        return first
 
     def _admit_batch_prefix(self, reqs: List[Request], slot_idx: List[int],
                             lb: int,
@@ -1115,8 +1153,6 @@ class DecodeRuntime:
             if self.record_tokens:
                 self._log_tokens(r.rid, [first_of[i]])
         self.peak_slots = max(self.peak_slots, self.slots_in_use)
-        self._note_admission(reqs, {id(r): grants[id(r)]["kind"]
-                                    for r in reqs}, lb)
         return self._harvest(0)
 
     # ------------------------------------------------------- copy-on-write
@@ -1179,70 +1215,84 @@ class DecodeRuntime:
         self.slots[i] = _Slot()
 
     def _harvest(self, steps: int) -> List[Finished]:
-        # nested profiler phase: retirement runs inside pump.admit /
-        # pump.decode (the fused tail finishes rows) — counted both places
-        t0 = time.perf_counter() if self.profiler is not None else 0.0
-        done = []
-        for i, s in enumerate(self.slots):
-            if not s.busy:
-                continue
-            s.remaining -= min(steps, s.remaining)
-            if s.remaining == 0:
-                done.append(Finished(s.req, s.req.max_new))
-                self._retire_slot(i)
-                # content store follows the live request set (re-mintable
-                # deterministically) — no monotonic growth across a stream
-                self.content.pop(s.req.rid, None)
-        if self.profiler is not None:
-            self.profiler.add("pump.retire", time.perf_counter() - t0)
+        with self._region("harvest") as sp:
+            done = []
+            for i, s in enumerate(self.slots):
+                if not s.busy:
+                    continue
+                s.remaining -= min(steps, s.remaining)
+                if s.remaining == 0:
+                    done.append(Finished(s.req, s.req.max_new))
+                    self._retire_slot(i)
+                    # content store follows the live request set (re-
+                    # mintable deterministically) — no monotonic growth
+                    # across a stream
+                    self.content.pop(s.req.rid, None)
+            if sp is not None:
+                sp.attrs["finished"] = len(done)
         return done
 
     def _decode_block(self) -> List[Finished]:
+        """One fused decode block over every row of the table, in the
+        ``decode`` region: its shape and its live row-steps (each busy row
+        computes ``steps`` and delivers ``min(steps, remaining)`` of them;
+        free rows and the overflow row deliver none)."""
         rcfg = self.kernels.rcfg
         if rcfg.spec_decode and self.spec_enabled:
             return self._spec_block()
-        maxrem = max((s.remaining for s in self.slots if s.busy), default=0)
-        steps = next((b for b in self.kernels.rcfg.block_ladder
-                      if b >= maxrem), self.kernels.rcfg.decode_block)
-        if self._paged:
-            self._cow_before_write(
-                [(i, s.pos + min(steps, s.remaining))
-                 for i, s in enumerate(self.slots) if s.busy])
-            fn = self.kernels.decode_fn(steps, self._kv_bucket(steps))
-            kw = {"pages": self._device_pages()}
-        else:
-            # engage dense block skipping only when the deepest live row
-            # leaves at least half the slab capacity dead this block —
-            # with a well-utilized slab the single fused attention wins
-            depth = max((s.pos + min(steps, s.remaining)
-                         for s in self.slots if s.busy), default=0)
-            skip = bool(rcfg.block_skip) and 2 * depth <= rcfg.capacity
-            fn = self.kernels.decode_fn(steps, skip=skip)
-            kw = {}
-        before = {i: s.remaining for i, s in enumerate(self.slots) if s.busy}
-        if self.tracer is not None:
-            self.tracer.span("decode", self.sim_now, replica=self.name,
-                             steps=steps,
-                             rids=tuple(self.slots[i].req.rid
-                                        for i in before))
-        self.tok, self.cache, self.active, self.remaining, toks, _ = fn(
-            self.params, self.tok, self.cache, self.active, self.remaining,
-            **kw)
-        self.steps_dispatched += 1
-        if self.record_tokens or rcfg.spec_decode:  # syncs per block
-            arr = np.asarray(toks)
-            for i, rem in before.items():
-                s = self.slots[i]
-                emitted = [int(t) for t in arr[:min(steps, rem), i]]
-                if self.record_tokens:
-                    self._log_tokens(s.req.rid, emitted)
-                if rcfg.spec_decode and emitted and s.history is not None:
-                    # keep the drafter's host mirrors current while spec
-                    # is browned out, so re-enabling it later verifies
-                    # against the true last token instead of a stale one
-                    s.history.extend(emitted)
-                    s.last_tok = emitted[-1]
-        return self._harvest(steps)
+        with self._region("decode") as sp:
+            maxrem = max((s.remaining for s in self.slots if s.busy),
+                         default=0)
+            steps = next((b for b in self.kernels.rcfg.block_ladder
+                          if b >= maxrem), self.kernels.rcfg.decode_block)
+            kvb = 0
+            if self._paged:
+                self._cow_before_write(
+                    [(i, s.pos + min(steps, s.remaining))
+                     for i, s in enumerate(self.slots) if s.busy])
+                kvb = self._kv_bucket(steps)
+                fn = self.kernels.decode_fn(steps, kvb)
+                kw = {"pages": self._device_pages()}
+            else:
+                # engage dense block skipping only when the deepest live
+                # row leaves at least half the slab capacity dead this
+                # block — with a well-utilized slab the single fused
+                # attention wins
+                depth = max((s.pos + min(steps, s.remaining)
+                             for s in self.slots if s.busy), default=0)
+                skip = bool(rcfg.block_skip) and 2 * depth <= rcfg.capacity
+                fn = self.kernels.decode_fn(steps, skip=skip)
+                kw = {}
+            before = {i: s.remaining for i, s in enumerate(self.slots)
+                      if s.busy}
+            if sp is not None:
+                sp.attrs.update(
+                    steps=steps, kvb=kvb,
+                    rids=tuple(self.slots[i].req.rid for i in before),
+                    rows=rcfg.max_batch + 1,
+                    live_row_steps=sum(min(steps, r)
+                                       for r in before.values()))
+            self.tok, self.cache, self.active, self.remaining, toks, _ = fn(
+                self.params, self.tok, self.cache, self.active,
+                self.remaining, **kw)
+            self.steps_dispatched += 1
+            if self.record_tokens or rcfg.spec_decode:  # syncs per block
+                with self._region("decode_read"):
+                    arr = np.asarray(toks)
+                for i, rem in before.items():
+                    s = self.slots[i]
+                    emitted = [int(t) for t in arr[:min(steps, rem), i]]
+                    if self.record_tokens:
+                        self._log_tokens(s.req.rid, emitted)
+                    if (rcfg.spec_decode and emitted
+                            and s.history is not None):
+                        # keep the drafter's host mirrors current while
+                        # spec is browned out, so re-enabling it later
+                        # verifies against the true last token instead of
+                        # a stale one
+                        s.history.extend(emitted)
+                        s.last_tok = emitted[-1]
+            return self._harvest(steps)
 
     # ------------------------------------------------------ spec decode
     def _spec_init(self, s: _Slot, first: int) -> None:
@@ -1368,38 +1418,22 @@ class DecodeRuntime:
         Finished slots free mid-stream; arrivals join the very next block.
         Loops on pending too: when a whole admission finishes inside its
         fused tail, the slots it freed must be refilled before returning."""
-        done = self._timed_admit()
+        done = self._admit_some()
         while any(s.busy for s in self.slots) or self.pending:
             if any(s.busy for s in self.slots):
-                done.extend(self._timed_decode())
-            done.extend(self._timed_admit())
+                done.extend(self._decode_block())
+            done.extend(self._admit_some())
         return done
 
     def step(self) -> List[Finished]:
         """One admission + one fused block (partial progress — lets callers
         interleave checkpoints or new arrivals between blocks)."""
-        done = self._timed_admit()
+        done = self._admit_some()
         if not any(s.busy for s in self.slots):
             return done
-        done.extend(self._timed_decode())
-        done.extend(self._timed_admit())
+        done.extend(self._decode_block())
+        done.extend(self._admit_some())
         return done
-
-    def _timed_admit(self) -> List[Finished]:
-        if self.profiler is None:
-            return self._admit_some()
-        t0 = time.perf_counter()
-        out = self._admit_some()
-        self.profiler.add("pump.admit", time.perf_counter() - t0)
-        return out
-
-    def _timed_decode(self) -> List[Finished]:
-        if self.profiler is None:
-            return self._decode_block()
-        t0 = time.perf_counter()
-        out = self._decode_block()
-        self.profiler.add("pump.decode", time.perf_counter() - t0)
-        return out
 
     # --------------------------------------------------------- checkpoint
     def partial_tokens(self) -> int:

@@ -10,6 +10,7 @@ import json
 import math
 import pathlib
 import sys
+import time
 
 import pytest
 
@@ -24,7 +25,7 @@ from repro.core.observability import (FlightRecorder, SLOConfig,
 from repro.core.tracing import NULL_TRACER, Tracer
 from repro.data.pipeline import Request, RequestSource
 from repro.streaming.engine import StreamEngine
-from repro.streaming.runtime import RuntimeConfig
+from repro.streaming.runtime import DecodeRuntime, RuntimeConfig
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "tools"))
@@ -159,6 +160,63 @@ def test_tracer_incarnation_bumps_on_restore_and_block_spans_match():
     assert NULL_TRACER.span("x", 0.0) is None and not NULL_TRACER.spans
     d = tr.dump()
     assert d[1]["attrs"]["rids"] == [7, 9]    # JSON-safe (tuple -> list)
+
+
+def test_tracer_region_times_host_work_in_start_order():
+    tr = Tracer()
+    t0 = time.perf_counter()
+    with tr.region("decode", 2.0, rids=(4,), steps=16) as sp:
+        tr.span("admit", 2.0, rid=4)
+        time.sleep(0.01)
+        sp.attrs["finished"] = 1
+    t1 = time.perf_counter()
+    dec, adm = tr.spans
+    assert (dec.name, adm.name) == ("decode", "admit")     # start order
+    assert dec.seq < adm.seq and dec.t == 2.0
+    assert t0 <= dec.wall <= adm.wall <= dec.end <= t1
+    assert dec.dur >= 0.01 and adm.dur == 0.0
+    assert tr.chain(4) == [dec, adm]
+    d = tr.dump()[0]
+    assert d["attrs"] == {"rids": [4], "steps": 16, "finished": 1}
+    assert (d["wall"], d["dur"]) == (dec.wall, dec.dur)
+    with NULL_TRACER.region("decode") as off:
+        assert off is None
+    assert not NULL_TRACER.spans
+
+
+# ------------------------------------------------ runtime host regions
+
+def test_runtime_regions_stamp_admission_first_token_and_live_rows():
+    """A runtime with a tracer: each rid is admitted before its first
+    token reaches the host, which is before ``step()`` returns; the fused
+    block books the row-steps that deliver tokens against those the
+    whole table computes."""
+    cfg = get_config("qwen2-7b").reduced()
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
+    rt = DecodeRuntime(serving.runtime_kernels(RuntimeConfig(
+        max_batch=4, paged=True, page_size=16, admit_tail=0)),
+        serving.params, record_tokens=True, tracer=Tracer())
+    rt.submit([Request(1, 0.0, 8, 3), Request(2, 0.0, 12, 16)])
+    rt.step()
+    returned = time.perf_counter()
+    tr = rt.tracer
+    for rid in (1, 2):
+        names = [s.name for s in tr.chain(rid)]
+        assert names[:4] == ["admit", "prefill", "first_token", "decode"]
+        adm, _, first, _ = tr.chain(rid)[:4]
+        assert adm.wall <= first.end <= returned
+        assert len(rt.token_log[rid]) >= 1
+    dec = next(s for s in tr.spans if s.name == "decode")
+    assert (dec.attrs["steps"], dec.attrs["rows"]) == (16, 5)
+    assert dec.attrs["live_row_steps"] == 19             # 3 + 16 of 80
+    inner = [s for s in tr.spans
+             if dec.wall <= s.wall and s.end <= dec.end and s is not dec]
+    assert {"decode_read", "harvest"} <= {s.name for s in inner}
+    harvest = [s for s in inner if s.name == "harvest"]
+    assert harvest[-1].attrs["finished"] == 2            # both retired
+    names = {s.name for s in tr.spans}
+    assert {"admit_plan", "pages_commit"} <= names
+    assert all(s.dur > 0 for s in tr.spans if s.name != "admit")
 
 
 # -------------------------------------------------------------- profiler
