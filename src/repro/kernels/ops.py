@@ -142,9 +142,9 @@ def decode_attention_paged(q, k_pool, v_pool, pages, lengths, *, kv_bucket,
     capacity. ``ctx`` carries the serving mesh (see
     ``_paged_kernel_call``).
 
-    pallas mode: the paged kernel reads the bucket's pages straight from
-    the pool via scalar-prefetch indexing (no gather) and skips each
-    row's pages past its length. jnp mode: gather the bucket's pages per
+    pallas mode: the paged kernel copies the bucket's pages straight from
+    the pool by DMA, several a grid step (no gather), and skips each
+    row's blocks past its length. jnp mode: gather the bucket's pages per
     row and run the block-skip streaming decode over them.
     """
     npg = kv_bucket // page_size

@@ -2,24 +2,34 @@
 
 The KV cache is a shared physical pool of fixed-size pages, stored
 head-major: ``(n_pages, Hkv, page_size, dh)`` per layer. Each batch row
-owns a small page table mapping its logical KV blocks to physical pages.
+owns a page table mapping its logical pages to physical pages (page 0 is
+the null page, reachable only past a row's length).
 
-The grid is ``(B, logical_pages)``. Step ``(b, j)`` loads one whole
-physical page of row ``b`` — every KV head at once, block
-``(1, Hkv, page_size, dh)`` — and the row's query as a
-``(1, Hkv, G, dh)`` block (the ``G = Hq // Hkv`` query heads of each KV
-group side by side), so each page is fetched once for all query heads
-that read it. Both blocks end in dimensions the TPU tiling accepts:
-``(page_size, dh)`` with page_size a multiple of 8, and ``(G, dh)``
-equal to the array's own trailing dimensions.
+One grid step covers ``ppb`` consecutive logical pages of a row, a block
+of ``ppb * page_size`` entries, so the fixed cost of a step and the
+matmuls are spread over many pages. ``ppb`` follows from the shapes the
+kernel sees (``pages_per_block``): the fewest pages whose K block reaches
+``BLOCK_BYTES``, at most the ``P`` pages a row spans. Under ``shard_map``
+the page bytes are the shard's own.
 
-The page table rides in as a scalar-prefetch operand, so the BlockSpec
-index map fetches each row's *physical* page. Pages at or beyond a row's
-live length repeat the last live page's block index (no new DMA) and
-their compute is skipped by ``pl.when`` — decode work tracks the tokens a
-request holds, not the pool. The streaming log-sum-exp reduction matches
-the jnp path's masking semantics (causal-by-length, sliding window,
-chunked, logit soft-cap).
+The grid is ``(B, ceil(P / ppb))``. The pools stay where they are
+(``pl.ANY``); step ``(b, j)`` copies the physical pages of block ``j`` of
+row ``b`` into VMEM scratch ``(2, Hkv, ppb * page_size, dh)`` with
+``make_async_copy``, the page ids read from the scalar-prefetched table.
+Only pages below the row's live length are copied. The two scratch slots
+double-buffer: while a block is computed, the next live block is already
+being fetched, the next row's first block at a row's last live step.
+Blocks wholly past a row's length start neither copy nor compute.
+
+The row's query rides in as a ``(1, Hkv, G, dh)`` block (the
+``G = Hq // Hkv`` query heads of each KV group side by side), so each page
+is fetched once for all the query heads that read it. Per block and KV
+head the math is one ``(G, ppb * page_size)`` score tile and one
+``(G, dh)`` accumulate of a streaming log-sum-exp, in float32 with K and
+V upcast from storage. Masking is per token and matches the jnp path:
+causal by length, sliding window, chunked, logit soft-cap. Entries past
+the length hold whatever the scratch held before; their scores are masked
+and their V rows zeroed, so none of it reaches the accumulator.
 """
 from __future__ import annotations
 
@@ -31,13 +41,49 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+BLOCK_BYTES = 512 * 1024       # K bytes one grid step fetches, at least
 
 
-def _kernel(pages_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, scale, window, chunk, softcap, ps,
-            n_pg, n_kv):
+def pages_per_block(page_bytes: int, n_pages: int) -> int:
+    """Pages one grid step covers: the fewest whose K block reaches
+    ``BLOCK_BYTES``, at least 1 and at most the row's ``n_pages``."""
+    return max(1, min(n_pages, -(-BLOCK_BYTES // page_bytes)))
+
+
+def _kernel(pages_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, slot_ref, acc_ref, m_ref, l_ref, *,
+            scale, window, chunk, softcap, ps, ppb, n_pg, n_blk, n_kv):
     b = pl.program_id(0)
     j = pl.program_id(1)
+    rows = pl.num_programs(0)
+    bk = ppb * ps
+
+    def depth(row):
+        # a retired row keeps its last depth over the null page, which
+        # may pass the bucket: it reads no further than its table
+        return jnp.minimum(len_ref[row], n_pg * ps)
+
+    def live_blocks(row):                    # block 0 always counts
+        return jnp.maximum((depth(row) + bk - 1) // bk, 1)
+
+    def page_copies(row, blk, slot, act):
+        """``act`` on the K and V copy of each page of block ``blk`` of
+        ``row`` that lies below the row's length. A loop, not ``ppb``
+        unrolled copies: every decode program lowers this body, and its
+        size is set-up time."""
+        n_live = jnp.clip((depth(row) + ps - 1) // ps - blk * ppb, 0, ppb)
+
+        def one_page(i, carry):
+            page = pages_ref[row, blk * ppb + i]
+            at = pl.ds(pl.multiple_of(i * ps, ps), ps)
+            for kv, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                              (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(pool.at[page],
+                                          buf.at[slot, :, at],
+                                          sems.at[kv, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, n_live, one_page, 0)
 
     @pl.when(j == 0)
     def _init():
@@ -45,26 +91,43 @@ def _kernel(pages_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[b]
-    qpos = length - 1
-
-    @pl.when(j * ps < length)
+    @pl.when(j < live_blocks(b))
     def _compute():
-        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        @pl.when((b == 0) & (j == 0))
+        def _first():
+            slot_ref[0] = 0
+            page_copies(0, 0, 0, lambda c: c.start())
+
+        slot = slot_ref[0]
+        row_done = j + 1 >= live_blocks(b)
+        nxt_b = jnp.where(row_done, b + 1, b)
+        nxt_j = jnp.where(row_done, 0, j + 1)
+
+        @pl.when(nxt_b < rows)
+        def _prefetch():
+            page_copies(nxt_b, nxt_j, 1 - slot, lambda c: c.start())
+            slot_ref[0] = 1 - slot
+
+        page_copies(b, j, slot, lambda c: c.wait())
+        length = depth(b)
+        qpos = length - 1
+        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         ok = kpos < length
         if window is not None:
             ok &= (qpos - kpos) < window
         if chunk is not None:
             ok &= (qpos // chunk) == (kpos // chunk)
+        held = (j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+                < length)
         for h in range(n_kv):                  # static: Hkv is small
             q = q_ref[0, h].astype(jnp.float32) * scale       # (G, dh)
-            k = k_ref[0, h].astype(jnp.float32)               # (ps, dh)
-            v = v_ref[0, h].astype(jnp.float32)
+            k = k_buf[slot, h].astype(jnp.float32)            # (bk, dh)
+            v = jnp.where(held, v_buf[slot, h].astype(jnp.float32), 0.0)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             if softcap:
                 s = jnp.tanh(s / softcap) * softcap
-            s = jnp.where(ok, s, NEG)                         # (G, ps)
+            s = jnp.where(ok, s, NEG)                         # (G, bk)
             m_prev = m_ref[h]                                 # (G, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -75,7 +138,7 @@ def _kernel(pages_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                 preferred_element_type=jnp.float32)
             m_ref[h] = m_new
 
-    @pl.when(j == n_pg - 1)
+    @pl.when(j == n_blk - 1)
     def _done():
         o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
@@ -92,27 +155,26 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, pages, lengths, *,
     Hkv, ps = k_pool.shape[1], k_pool.shape[2]
     P = pages.shape[1]
     G = Hq // Hkv
+    ppb = pages_per_block(Hkv * ps * dh * k_pool.dtype.itemsize, P)
+    n_blk = -(-P // ppb)
     kernel = functools.partial(_kernel, scale=dh ** -0.5, window=window,
-                               chunk=chunk, softcap=softcap, ps=ps, n_pg=P,
-                               n_kv=Hkv)
-
-    def kv_map(b, j, pt, lt):
-        # block j of row b is whatever physical page the table names; past
-        # the live length the last live page repeats, so no DMA is issued
-        last = jnp.maximum(lt[b] - 1, 0) // ps
-        return (pt[b, jnp.minimum(j, last)], 0, 0, 0)
-
+                               chunk=chunk, softcap=softcap, ps=ps, ppb=ppb,
+                               n_pg=P, n_blk=n_blk, n_kv=Hkv)
     q_spec = pl.BlockSpec((1, Hkv, G, dh), lambda b, j, pt, lt: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, P),
+        grid=(B, n_blk),
         in_specs=[
             q_spec,
-            pl.BlockSpec((1, Hkv, ps, dh), kv_map),
-            pl.BlockSpec((1, Hkv, ps, dh), kv_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=q_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, Hkv, ppb * ps, dh), k_pool.dtype),
+            pltpu.VMEM((2, Hkv, ppb * ps, dh), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),      # (K|V, slot)
+            pltpu.SMEM((1,), jnp.int32),          # slot of the current block
             pltpu.VMEM((Hkv, G, dh), jnp.float32),
             pltpu.VMEM((Hkv, G, 1), jnp.float32),
             pltpu.VMEM((Hkv, G, 1), jnp.float32),
@@ -122,6 +184,9 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, pages, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dh), q.dtype),
+        # a block's prefetch reaches into the next row: rows run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
     )(pages.astype(jnp.int32), lengths.astype(jnp.int32),

@@ -35,7 +35,8 @@ def attention_ref(q, k, v, *, causal=True, window=None, chunk=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, vg.astype(jnp.float32)).astype(q.dtype)
 
 
-def decode_attention_ref(q, k, v, *, lengths, window=None, chunk=None):
+def decode_attention_ref(q, k, v, *, lengths, window=None, chunk=None,
+                         softcap=0.0):
     """q: (B,Hq,dh); k,v: (B,Skmax,Hkv,dh); lengths: (B,) valid cache length.
     Query position = lengths - 1."""
     B, Hq, dh = q.shape
@@ -45,6 +46,8 @@ def decode_attention_ref(q, k, v, *, lengths, window=None, chunk=None):
     vg = jnp.repeat(v, G, axis=2)
     s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
                    kg.astype(jnp.float32)) * (dh ** -0.5)
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
     qpos = (lengths - 1)[:, None, None]
     kpos = jnp.arange(Sk)[None, None, :]
     ok = kpos < lengths[:, None, None]

@@ -51,6 +51,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.tracing import NULL_REGION
 from repro.data.pipeline import Request
+from repro.kernels.paged_decode_attention import pages_per_block
 from repro.models import model_api as MA
 
 
@@ -700,6 +701,23 @@ class DecodeRuntime:
         ladder = self.kernels.rcfg.kv_ladder
         return next((b for b in ladder if b >= need), ladder[-1])
 
+    def _kv_blocks(self, steps: int, kvb: int) -> dict:
+        """The paged kernel's grid in one layer call at a block's last
+        step: ``kv_blocks`` the grid steps it walks (rows x ceil(kvb /
+        block)), ``live_kv_blocks`` the blocks below a busy row's depth
+        (ceil(depth / block) a busy row). A block is the kernel's own
+        ``pages_per_block`` pages, from the pool shard's page bytes."""
+        ps = self.kernels.rcfg.page_size
+        kv = next(self.cache[p]["k"] for p in ("dense", "moe")
+                  if p in self.cache)
+        shard = kv.sharding.shard_shape(kv.shape)  # (L, n, Hkv, ps, dh)
+        page_bytes = int(np.prod(shard[2:])) * kv.dtype.itemsize
+        bk = ps * pages_per_block(page_bytes, kvb // ps)
+        live = sum(-(-(s.pos + min(steps, s.remaining)) // bk)
+                   for s in self.slots if s.busy)
+        return {"kv_blocks": len(self.page_table) * -(-kvb // bk),
+                "live_kv_blocks": live}
+
     # -------------------------------------------------------------- intake
     def submit(self, requests: List[Request],
                force: bool = False) -> List[Request]:
@@ -1272,6 +1290,8 @@ class DecodeRuntime:
                     rows=rcfg.max_batch + 1,
                     live_row_steps=sum(min(steps, r)
                                        for r in before.values()))
+                if self._paged:
+                    sp.attrs.update(self._kv_blocks(steps, kvb))
             self.tok, self.cache, self.active, self.remaining, toks, _ = fn(
                 self.params, self.tok, self.cache, self.active,
                 self.remaining, **kw)

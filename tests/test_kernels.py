@@ -1,5 +1,7 @@
 """Pallas kernel validation: shape/dtype sweeps + hypothesis properties,
 all against the pure-jnp oracles in repro.kernels.ref (interpret mode)."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ from repro.kernels import ref as R
 from repro.kernels.decode_attention import decode_attention_kernel
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mlstm_scan import mlstm_chunkwise_kernel
+from repro.kernels import paged_decode_attention as PDA
 from repro.kernels.paged_decode_attention import paged_decode_attention_kernel
 from repro.kernels.ssm_scan import ssm_scan_kernel
 from repro.models.attention import blockwise_attention, decode_attention
@@ -228,19 +231,79 @@ def _gather(pool, pages):
     return g.reshape(B, P * pool.shape[2], pool.shape[1], pool.shape[3])
 
 
-@pytest.mark.parametrize("window,chunk", [(None, None), (11, None),
-                                          (None, 16)])
-def test_paged_decode_attention_kernel_vs_gathered_ref(window, chunk):
-    """Paged kernel (scalar-prefetch page table, per-row early exit over
-    the page grid) == gather-the-pages-then-dense-oracle."""
-    q, kp, vp, pages, lengths = _paged_case(0)
-    out = paged_decode_attention_kernel(q, kp, vp, pages, lengths,
-                                        window=window, chunk=chunk,
-                                        interpret=True)
+def _paged_rows(seed, lengths, P, B_free=(), Hq=4, Hkv=2, dh=16, ps=8):
+    """Rows of the given ``lengths`` over a shuffled pool: each row's
+    pages are drawn out of order, with gaps, from a pool twice the size
+    needed. Rows in ``B_free`` are free: every entry is the null page."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    n_pages = 2 * B * P + 1
+    q = jnp.asarray(rng.normal(size=(B, Hq, dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, Hkv, ps, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, Hkv, ps, dh)), jnp.float32)
+    ids = iter(rng.permutation(np.arange(1, n_pages)))  # page 0 = null
+    pages = np.zeros((B, P), np.int32)
+    for b, n in enumerate(lengths):
+        if b not in B_free:
+            pages[b, :-(-n // ps)] = [next(ids) for _ in range(-(-n // ps))]
+    return q, kp, vp, jnp.asarray(pages), jnp.asarray(lengths, jnp.int32)
+
+
+@contextlib.contextmanager
+def _pages_per_step(ppb, kp):
+    """The kernel covers ``ppb`` pages a grid step at the test's small
+    page size (the rule's byte target set to ppb pages); None keeps the
+    rule's own choice."""
+    with pytest.MonkeyPatch.context() as m:
+        if ppb is not None:
+            m.setattr(PDA, "BLOCK_BYTES",
+                      ppb * kp[0].size * kp.dtype.itemsize)
+        yield
+
+
+# block = 3 pages of 8 = 24 entries unless ppb says otherwise; P = 7 is
+# not a multiple of 3, so the last block holds one page
+PAGED_CASES = {
+    "None-None": dict(),
+    "11-None": dict(window=11),
+    "None-16": dict(chunk=16),
+    "edges": dict(ppb=3, P=7, lengths=(21, 24, 25, 56)),
+    "free_row": dict(ppb=3, P=7, lengths=(40, 1, 56), free=(1,)),
+    "window_in_block": dict(ppb=3, P=7, lengths=(56, 30, 45), window=13),
+    "chunk_across_block": dict(ppb=3, P=7, lengths=(56, 30, 45), chunk=20),
+    "softcap": dict(ppb=3, P=7, lengths=(56, 30, 5), softcap=5.0),
+    "page_a_step": dict(ppb=1, P=5, lengths=(33, 8, 17)),
+    "stale_depth": dict(ppb=3, P=4, lengths=(32, 90, 9), free=(1,)),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_paged_decode_attention_kernel_vs_gathered_ref(case):
+    """Paged kernel (scalar-prefetch page table, several pages a grid step
+    gathered by DMA, per-row early exit) == gather-the-pages-then-dense-
+    oracle. Lengths end mid-page, on a block edge and one past it; a free
+    row reads the null page; a retired row's stale depth passes the
+    bucket (its output is not compared: the kernel reads no further than
+    the table)."""
+    c = PAGED_CASES[case]
+    if "lengths" in c:
+        q, kp, vp, pages, lengths = _paged_rows(0, c["lengths"], c["P"],
+                                                c.get("free", ()))
+    else:
+        q, kp, vp, pages, lengths = _paged_case(0)
+    kw = dict(window=c.get("window"), chunk=c.get("chunk"),
+              softcap=c.get("softcap", 0.0))
+    with _pages_per_step(c.get("ppb"), kp):
+        out = paged_decode_attention_kernel(q, kp, vp, pages, lengths,
+                                            interpret=True, **kw)
+    P, ps = pages.shape[1], kp.shape[2]
+    keep = np.asarray(lengths) <= P * ps
     kb, vb = _gather(kp, pages), _gather(vp, pages)
-    ref = R.decode_attention_ref(q, kb, vb, lengths=lengths, window=window,
-                                 chunk=chunk)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    ref = R.decode_attention_ref(q, kb, vb,
+                                 lengths=jnp.minimum(lengths, P * ps), **kw)
+    assert np.all(np.isfinite(np.asarray(out)))
+    np.testing.assert_allclose(np.asarray(out)[keep], np.asarray(ref)[keep],
+                               atol=2e-5, rtol=2e-5)
 
 
 @settings(max_examples=8, deadline=None)
@@ -249,14 +312,34 @@ def test_paged_decode_attention_property(data):
     seed = data.draw(st.integers(0, 99))
     B = data.draw(st.integers(1, 4))
     ps = data.draw(st.sampled_from([4, 8, 16]))
-    P = data.draw(st.sampled_from([2, 4]))
+    P = data.draw(st.sampled_from([2, 4, 5, 7]))
+    ppb = data.draw(st.sampled_from([None, 1, 2, 3]))
+    window = data.draw(st.sampled_from([None, 5, 19]))
     q, kp, vp, pages, lengths = _paged_case(seed, B=B, ps=ps, P=P,
                                             n_pages=B * P + 2)
-    out = paged_decode_attention_kernel(q, kp, vp, pages, lengths,
-                                        interpret=True)
+    with _pages_per_step(ppb, kp):
+        out = paged_decode_attention_kernel(q, kp, vp, pages, lengths,
+                                            window=window, interpret=True)
     kb, vb = _gather(kp, pages), _gather(vp, pages)
-    ref = R.decode_attention_ref(q, kb, vb, lengths=lengths)
+    ref = R.decode_attention_ref(q, kb, vb, lengths=lengths, window=window)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+def test_pages_per_block_rule():
+    """Pages a grid step covers follow from the page's bytes and the
+    row's pages alone: 32 at qwen2-7b widths (4 KV heads x 16 x 128 in
+    bf16 = 16 KiB a page; the fastest of 4, 8, 16 and 32 on a v5e), more
+    for a tensor-parallel shard's smaller page, never fewer than 1 nor
+    more than the row has."""
+    page = 4 * 16 * 128 * 2
+    assert PDA.pages_per_block(page, 96) == 32
+    assert PDA.pages_per_block(page, 130) == 32
+    assert PDA.pages_per_block(page, 5) == 5
+    assert PDA.pages_per_block(page // 4, 96) == 96       # Hkv 1 a shard
+    assert PDA.pages_per_block(page // 4, 300) == 128
+    for pb in (256, 4096, 16384, 65536, 1 << 20):
+        for P in (1, 2, 7, 96, 130):
+            assert 1 <= PDA.pages_per_block(pb, P) <= P
 
 
 def test_kernel_mode_routes_paged_dispatch():

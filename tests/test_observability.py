@@ -219,6 +219,36 @@ def test_runtime_regions_stamp_admission_first_token_and_live_rows():
     assert all(s.dur > 0 for s in tr.spans if s.name != "admit")
 
 
+def test_runtime_decode_region_counts_kernel_blocks(monkeypatch):
+    """The ``decode`` region books the paged kernel's grid in one layer
+    call at the block's last step: every row's ceil(kvb / block) grid
+    steps, and each busy row's ceil(depth / block) live ones, with the
+    block the kernel's own (here 2 pages of 16 = 32 entries)."""
+    from repro.kernels import paged_decode_attention as PDA
+    cfg = get_config("qwen2-7b").reduced()
+    page_bytes = cfg.n_kv_heads * 16 * cfg.head_dim * cfg.jdtype.itemsize
+    monkeypatch.setattr(PDA, "BLOCK_BYTES", 2 * page_bytes)
+    serving = ElasticServing(cfg, tp=1).build(1, seed=0)
+    rt = DecodeRuntime(serving.runtime_kernels(RuntimeConfig(
+        max_batch=4, paged=True, page_size=16, admit_tail=0)),
+        serving.params, record_tokens=True, tracer=Tracer())
+    # prompt buckets 8, 16, 64; decode starts at the bucket's depth
+    rt.submit([Request(1, 0.0, 8, 3), Request(2, 0.0, 12, 40),
+               Request(3, 0.0, 40, 30)])
+    for _ in range(3):
+        rt.step()
+    got = [(s.attrs["steps"], s.attrs["kvb"], s.attrs["kv_blocks"],
+            s.attrs["live_kv_blocks"])
+           for s in rt.tracer.spans if s.name == "decode"]
+    assert got == [
+        # depths 8+3, 16+16, 64+16 -> 1 + 1 + 3 live; 5 rows x ceil(80/32)
+        (16, 80, 15, 5),
+        # depths 32+16, 80+14 -> 2 + 3; 5 rows x ceil(96/32)
+        (16, 96, 15, 5),
+        # depth 48+8 -> 2; 5 rows x ceil(64/32)
+        (8, 64, 10, 2)]
+
+
 # -------------------------------------------------------------- profiler
 
 def test_tick_profiler_accumulates_and_nests():

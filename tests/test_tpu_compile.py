@@ -57,12 +57,13 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _paged_args(sh, q_shape, pool_sh=None):
-    pool = (N_PAGES, HKV, PS, DH)
+def _paged_args(sh, q_shape, pool_sh=None, n_pg=P_SLOT):
+    rows = q_shape[0]
+    pool = (max(N_PAGES, (rows - 1) * n_pg + 1), HKV, PS, DH)
     return (_sds(q_shape, jnp.bfloat16, sh),
             _sds(pool, jnp.bfloat16, pool_sh or sh),
             _sds(pool, jnp.bfloat16, pool_sh or sh),
-            _sds((B, P_SLOT), jnp.int32, sh), _sds((B,), jnp.int32, sh))
+            _sds((rows, n_pg), jnp.int32, sh), _sds((rows,), jnp.int32, sh))
 
 
 def _assert_kernel(compiled):
@@ -71,6 +72,17 @@ def _assert_kernel(compiled):
 
 def test_paged_decode_kernel_compiles_for_v5e(one_chip):
     args = _paged_args(one_chip, (B, HQ, DH))
+    _assert_kernel(jax.jit(paged_decode_attention_kernel)
+                   .lower(*args).compile())
+
+
+@pytest.mark.parametrize("rows,n_pg", [(17, 96), (9, 130)])
+def test_paged_decode_kernel_compiles_at_deepest_buckets(one_chip, rows,
+                                                         n_pg):
+    """The chat and longdoc cells' deepest kv buckets (1536 and 2080
+    entries) over their max_batch + 1 rows: 32 pages a grid step, the
+    last block of the 130-page table holding two."""
+    args = _paged_args(one_chip, (rows, HQ, DH), n_pg=n_pg)
     _assert_kernel(jax.jit(paged_decode_attention_kernel)
                    .lower(*args).compile())
 
@@ -89,9 +101,7 @@ def test_flash_prefill_kernel_compiles_for_v5e(one_chip):
     _assert_kernel(jax.jit(flash_attention).lower(q, kv, kv).compile())
 
 
-@pytest.mark.parametrize("replicas,tp", [(1, 4), (2, 2)])
-def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch, replicas,
-                                              tp):
+def _compile_on_mesh(topo, monkeypatch, replicas, tp, n_pg):
     """(replicas, tp) serving meshes: the pool split over KV heads, the
     batch rows over replicas, the kernel run per shard under shard_map, as
     the serving runtime commits them."""
@@ -100,7 +110,7 @@ def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch, replicas,
     ctx = ShardCtx(mesh)
     rows = NamedSharding(mesh, P("data"))
     pool_sh = NamedSharding(mesh, P(None, "model"))
-    args = _paged_args(rows, (B, 1, HQ, DH), pool_sh)
+    args = _paged_args(rows, (B, 1, HQ, DH), pool_sh, n_pg=n_pg)
     # the ops layer asks the process's own backend (the CPU here) whether
     # to interpret; this compile is for the described TPU
     monkeypatch.setattr(ops, "_interpret", lambda: False)
@@ -108,7 +118,7 @@ def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch, replicas,
 
     def step(q, kp, vp, pages, lengths):
         return ops.decode_attention_paged(q, kp, vp, pages, lengths,
-                                          kv_bucket=P_SLOT * PS,
+                                          kv_bucket=n_pg * PS,
                                           page_size=PS, ctx=ctx)
 
     compiled = jax.jit(step).lower(*args).compile()
@@ -116,3 +126,17 @@ def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch, replicas,
     # each chip attends its own rows over its own KV heads: nothing is
     # gathered, neither the pool nor the queries
     assert "all-gather" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("replicas,tp", [(1, 4), (2, 2)])
+def test_paged_kernel_compiles_on_4_chip_mesh(topo, monkeypatch, replicas,
+                                              tp):
+    _compile_on_mesh(topo, monkeypatch, replicas, tp, P_SLOT)
+
+
+@pytest.mark.parametrize("replicas,tp", [(1, 4), (2, 2)])
+def test_paged_kernel_compiles_on_4_chip_mesh_deepest_bucket(
+        topo, monkeypatch, replicas, tp):
+    """The chat cell's deepest bucket on a mesh: each shard's page is
+    smaller (1 or 2 KV heads), so it covers more pages a grid step."""
+    _compile_on_mesh(topo, monkeypatch, replicas, tp, 96)
