@@ -43,6 +43,12 @@ def idle_share(trace, rec):
     return 100.0 * (1.0 - trace.mean_busy_s() / trace.window_s)
 
 
+def collective_share(trace, rec):
+    if trace is None or trace.window_s <= 0:
+        return None
+    return _pct(trace.mean(trace.collective_s), trace.window_s)
+
+
 def queue_wait_p90(trace, rec):
     waits = [r.admit - r.due for r in (rec.reqs[i] for i in rec.in_window)
              if r.admit is not None]

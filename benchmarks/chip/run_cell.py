@@ -8,8 +8,11 @@ A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a model
 configuration (``configs/<config>.json``) under a traffic mix
 (``traffic/<mix>.json``), with its replica sizing and correctness limits in
 ``cells/<cell>.json``. Per-layer metrics are readers in
-``metrics/<metric>.py``. Everything is found by name; nothing here names a
-cell.
+``metrics/<metric>.py``. What belongs to a model family (its shapes, the
+check of the program's configuration, seeded weights, the float32
+reference and the work counts) is in ``families/<model_type>.py``, named
+by the configuration's ``model_type``. Everything is found by name;
+nothing here names a cell or a model.
 
 Stages of a run, each reported on an earlier line of standard output:
 
@@ -29,11 +32,11 @@ Stages of a run, each reported on an earlier line of standard output:
    has finished, or ``drain_cap_s`` passes and it counts as failed.
 5. Programs built inside the window must be 0; a run that built one
    exits non-zero.
-6. ``correct``: once the program's state is freed, a plain float32
-   reference (``reference.py``) runs over a sample of the finished
-   requests, drawn from the seed with the longest among them, and every
-   served token's reference logit must lie within the cell's limit of the
-   reference's best.
+6. ``correct``: once the program's state is freed, the family's plain
+   float32 reference runs over a sample of the finished requests, drawn
+   from the seed with the longest among them, and every served token's
+   reference logit must lie within the cell's limit of the reference's
+   best.
 
 ``--trace 1`` runs the same cell with the profiler on for the last
 ``TRACE_FOR_S`` seconds of the window and reports the per-layer metrics and a breakdown instead
@@ -71,7 +74,6 @@ import numpy as np  # noqa: E402
 
 from benchmarks.chip import traffic as TR  # noqa: E402
 from benchmarks.chip import work as WK  # noqa: E402
-from benchmarks.chip.work import Dims  # noqa: E402
 
 TRACE_FOR_S = 4.0        # the profiler traces the window's last seconds
 COMPILE_THREADS = 8
@@ -87,6 +89,7 @@ class Cell:
     sizing: dict             # cells/<cell>.json
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: object           # families/<model_type>.py
 
 
 def load_cell(root: pathlib.Path, name: str) -> Cell:
@@ -103,23 +106,51 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name] if m["moves"] in reports
                                   else [])]
-    return Cell(name=name,
-                config=json.loads((root / conf["file"]).read_text()),
+    config = json.loads((root / conf["file"]).read_text())
+    return Cell(name=name, config=config,
                 mix=json.loads((here / "traffic" / f"{w['traffic']}.json")
                                .read_text()),
                 sizing=json.loads((here / "cells" / f"{name}.json")
                                   .read_text()),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                family=load_family(root, config, root / conf["file"]))
+
+
+def load_family(root: pathlib.Path, config: dict, where="the config"):
+    """The module of ``families/<model_type>.py`` under ``root``, for the
+    configuration's ``model_type``. There is no default family: a
+    configuration without one, or one with no module, is an error.
+
+    A family module provides, for the configuration's dict ``model``:
+    ``dims(model)``, a hashable shape object with at least ``vocab`` (the
+    traffic draws prompt ids below it); ``check(cfg, model)``, which raises
+    if the program's ``ArchConfig`` differs from the file;
+    ``seed_key(seed)`` and ``program_params(key, model, dtype)``, the
+    weights in the program's parameter tree; ``served_gaps(seed, model,
+    requests, *, rows, seq_len, out_len, control)``, the float32 reference
+    comparison and its control; and the work the traffic asked for,
+    ``prefill_flops(dims, plen, tp)``, ``decode_token_flops(dims, ctx, tp)``
+    and ``attn_decode(dims, ctx, tp)`` (flops, bytes), per chip."""
+    kind = config.get("model_type")
+    if not kind:
+        raise ValueError(f"{where} names no model_type")
+    path = root / "benchmarks" / "chip" / "families" / f"{kind}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"model_type {kind!r} of {where}: no "
+                                f"family module {path}")
+    return _module(f"bench_family_{kind}", path)
+
+
+def _module(name: str, path: pathlib.Path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_reader(name: str):
     """The module of ``metrics/<name>.py``."""
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module(f"bench_metric_{name}", HERE / "metrics" / f"{name}.py")
 
 
 def pow2_at_least(n: int) -> int:
@@ -137,21 +168,6 @@ def runtime_fields(cell: Cell) -> dict:
                max_new_cap=cell.mix["output_tokens"][1] - 1,
                max_batch=cell.sizing["max_batch"])
     return out
-
-
-def check_dims(cfg, model: dict) -> None:
-    """The program's configuration has to be the one the file states."""
-    m = Dims.of(model)
-    want = {"d_model": m.d, "n_layers": m.layers, "n_heads": m.heads,
-            "n_kv_heads": m.kv_heads, "head_dim": m.head_dim, "d_ff": m.ffn,
-            "vocab": m.vocab, "rope_theta": float(model["rope_theta"]),
-            "norm_eps": float(model["rms_norm_eps"]),
-            "qkv_bias": True, "mlp": "swiglu", "tie_embeddings": False}
-    have = {k: getattr(cfg, k) for k in want}
-    bad = {k: (have[k], want[k]) for k in want if have[k] != want[k]}
-    if bad:
-        raise ValueError(f"program config {cfg.name} differs from the "
-                         f"cell's file: {bad}")
 
 
 # ------------------------------------------------------- compile counting
@@ -200,20 +216,20 @@ class Replica:
     kernels: object
     rt: object
     rcfg: object
-    dims: Dims
+    dims: object             # the family's shapes
     tp: int
 
 
-def make_params(serving, model: dict, seed: int, dtype):
-    """Weights in the program's tree, drawn on the devices in one jitted
-    call with the program's own parameter shardings (those
+def make_params(serving, cell: Cell, seed: int, dtype):
+    """The family's weights in the program's tree, drawn on the devices in
+    one jitted call with the program's own parameter shardings (those
     ``ElasticServing`` places its parameters in)."""
     import jax
-    from benchmarks.chip import weights as W
+    fam, model = cell.family, cell.config
     psh = serving._lowered(1)[4]
-    m = Dims.of(model)
-    fn = jax.jit(lambda k: W.program_params(k, m, dtype), out_shardings=psh)
-    params = fn(W.seed_key(seed))
+    fn = jax.jit(lambda k: fam.program_params(k, model, dtype),
+                 out_shardings=psh)
+    params = fn(fam.seed_key(seed))
     jax.block_until_ready(params)
     return params
 
@@ -228,17 +244,18 @@ def build(cell: Cell, seed: int, cfg=None, serving=None) -> Replica:
     tp = int(cell.config["tp"])
     if serving is None:
         cfg = cfg if cfg is not None else get_config(cell.config["arch"])
-        check_dims(cfg, cell.config)
+        cell.family.check(cfg, cell.config)
         serving = ElasticServing(cfg, tp)
     serving.params = None
-    serving.params = make_params(serving, cell.config, seed,
+    serving.params = make_params(serving, cell, seed,
                                  jnp.dtype(serving.cfg.dtype))
     serving.build(1)
     rcfg = RuntimeConfig(**runtime_fields(cell))
     kernels = serving.runtime_kernels(rcfg)
     rt = DecodeRuntime(kernels=kernels, params=serving.params,
                        record_tokens=True)
-    return Replica(serving, kernels, rt, rcfg, Dims.of(cell.config), tp)
+    return Replica(serving, kernels, rt, rcfg,
+                   cell.family.dims(cell.config), tp)
 
 
 def reachable(rcfg, mix: dict):
@@ -322,7 +339,7 @@ class Req:
 @dataclass
 class Record:
     """What the drive saw, for the checks and the metric readers."""
-    dims: Dims
+    dims: object             # the family's shapes
     tp: int
     max_batch: int
     peak: dict = field(default_factory=dict)
@@ -351,25 +368,26 @@ def _annotate(name: str, on: bool):
     return jax.profiler.TraceAnnotation(name)
 
 
-def book_work(rec: Record) -> None:
-    """The work the traced steps' deliveries needed (``work.py``): token
-    0 of a request is its prefill of ``plen`` requested tokens; token
-    ``j`` >= 1 is a decode step over ``plen + j`` entries of context. The
-    pad up to the length bucket joins the program's context but is not
-    work the request asked for, so it is never booked."""
+def book_work(rec: Record, family) -> None:
+    """The work the traced steps' deliveries needed, as the family counts
+    it: token 0 of a request is its prefill of ``plen`` requested tokens;
+    token ``j`` >= 1 is a decode step over ``plen + j`` entries of
+    context. The pad up to the length bucket joins the program's context
+    but is not work the request asked for, so it is never booked."""
     w = dict.fromkeys(("prefill_flops", "prefill_tokens", "attn_flops",
                        "attn_bytes", "decode_flops", "decode_tokens"), 0)
     m, tp = rec.dims, rec.tp
     for rid, j0, n in rec.traced_tokens:
         plen = rec.reqs[rid].plen
         if j0 == 0:
-            w["prefill_flops"] += WK.prefill_flops(m, plen, tp)
+            w["prefill_flops"] += family.prefill_flops(m, plen, tp)
             w["prefill_tokens"] += plen
         for j in range(max(j0, 1), j0 + n):
-            f, b = WK.attn_decode(m, plen + j, tp)
+            f, b = family.attn_decode(m, plen + j, tp)
             w["attn_flops"] += f
             w["attn_bytes"] += b
-            w["decode_flops"] += WK.decode_token_flops(m, plen + j, tp)
+            w["decode_flops"] += family.decode_token_flops(m, plen + j,
+                                                           tp)
             w["decode_tokens"] += 1
     rec.work = w
 
@@ -504,13 +522,13 @@ def sample(rec: Record, seed: int, k: int) -> List[Req]:
 
 
 def compare(cell: Cell, seed: int, picked: List[Req], control=None):
-    """(widest served-token gap, the control's widest gap or None)."""
-    from benchmarks.chip import reference as REF
+    """(widest served-token gap, the control's widest gap or None), by the
+    family's reference."""
     if not picked:
         raise RuntimeError("no finished request in the window to check")
     lb_max = pow2_at_least(cell.mix["prompt_tokens"][1])
     out_hi = cell.mix["output_tokens"][1]
-    return REF.served_gaps(
+    return cell.family.served_gaps(
         seed, cell.config, [(r.prompt, np.asarray(r.tokens, np.int32))
                             for r in picked],
         rows=int(cell.sizing["reference_rows"]),
@@ -656,7 +674,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, cfg=None,
         if trace:
             from benchmarks.chip import trace as TRC
             rec.trace = TRC.load(trace_dir)
-            book_work(rec)
+            book_work(rec, cell.family)
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)

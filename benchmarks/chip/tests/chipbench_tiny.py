@@ -13,7 +13,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-MODEL = {"hidden_size": 128, "num_attention_heads": 4,
+MODEL = {"model_type": "qwen2", "hidden_size": 128, "num_attention_heads": 4,
          "num_key_value_heads": 1, "intermediate_size": 256,
          "vocab_size": 8192, "num_hidden_layers": 2, "rope_theta": 1e6,
          "rms_norm_eps": 1e-6, "arch": "qwen2-7b-1chip", "chips": 1,
@@ -54,4 +54,4 @@ def cell(arrivals="stratified_poisson"):
     return RC.Cell("tiny", dict(MODEL), mix,
                    {"max_batch": 4, "reference_rows": 2,
                     "limits": {"served_logit_gap": TINY_LIMIT}},
-                   e2e, per_layer)
+                   e2e, per_layer, RC.load_family(ROOT, MODEL))

@@ -87,3 +87,48 @@ def test_a_trace_recorded_on_the_cpu(tmp_path):
     idle = dict(t.idle_by_host())
     assert idle.get("bench.wait", 0.0) >= 0.05
     assert sum(idle.values()) == pytest.approx(t.window_s - busy, rel=1e-6)
+
+
+def test_collective_share_over_four_device_planes(tmp_path, monkeypatch):
+    """A v5e-4 trace as the profiler lays it out: one ``/device:TPU:<n>``
+    plane a chip, each with its ``XLA Modules`` and ``XLA Ops`` lines, and
+    the harness's spans on a host plane. The tests run on the CPU, which
+    leaves the exchange between chips out; the planes are written here."""
+    from types import SimpleNamespace as NS
+    from benchmarks.chip import run_cell as RC
+
+    def ev(name, start_s, dur_s):
+        return NS(name=name, start_ns=int(start_s * 1e9),
+                  duration_ns=int(dur_s * 1e9), stats=[])
+
+    def line(name, events):
+        return NS(name=name, events=events)
+
+    planes = []
+    for n in range(4):
+        ar = 0.1 * (n + 1)          # chip n: all-reduce time 0.1 (n + 1) s
+        ops = [ev("%fusion.1 = bf16[8] fusion(%p)", 1.0, 1.0),
+               ev("%all-reduce.7 = bf16[8] all-reduce(%f)", 2.0, ar),
+               ev("%all-gather.2 = bf16[8] all-gather(%g)", 12.0, 1.0)]
+        if n == 0:                  # overlapping async halves count once
+            ops.append(ev("%all-reduce-start.8 = bf16[8] "
+                          "all-reduce-start(%h)", 2.05, 0.05))
+        planes.append(NS(name=f"/device:TPU:{n}", lines=[
+            line("XLA Modules", [ev("jit_block(3)", 1.0, 2.0)]),
+            line("XLA Ops", ops)]))
+    planes.append(NS(name="/host:CPU", lines=[
+        line("python", [ev("bench.traced", 0.0, 10.0)])]))
+    (tmp_path / "x.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(jax.profiler, "ProfileData", NS(
+        from_file=lambda path: NS(planes=planes)))
+    t = TRC.load(str(tmp_path))
+    assert t.chips == [0, 1, 2, 3]
+    # the all-gather lies past the window's end
+    assert [t.collective_s(c) for c in t.chips] == pytest.approx(
+        [0.1, 0.2, 0.3, 0.4])
+    read = RC.metric_reader("collective_share.tp4").read
+    assert read(t, None) == pytest.approx(100.0 * 0.25 / 10.0)
+    assert read(None, None) is None
+    quiet = TRC.Trace([TRC.Op(c, "fusion.1", "jit_block", 1.0, 1.0)
+                       for c in range(4)], [], (0.0, 10.0), [0, 1, 2, 3])
+    assert read(quiet, None) is None

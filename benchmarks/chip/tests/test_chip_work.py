@@ -7,6 +7,7 @@ from benchmarks.chip import work as WK
 
 M = WK.Dims(d=8, layers=2, heads=4, kv_heads=2, head_dim=2, ffn=16,
             vocab=32)
+QWEN2 = {"model_type": "qwen2"}
 
 
 def test_layer_matmul_params():
@@ -43,7 +44,7 @@ def _rec(reqs, traced, peak=None):
     rec = RC.Record(M, 1, 4, peak=peak or {})
     rec.reqs = {r.rid: r for r in reqs}
     rec.traced_tokens = traced
-    RC.book_work(rec)
+    RC.book_work(rec, RC.load_family(chipbench_tiny.ROOT, QWEN2))
     return rec
 
 
@@ -65,6 +66,35 @@ def test_padding_is_not_booked():
                                        for j in range(1, 5))
     assert padded["attn_bytes"] < _rec([_req(1, 1024, 1024)],
                                        [(1, 0, 5)]).work["attn_bytes"]
+
+
+def test_tp4_books_a_quarter_of_the_matmuls_and_one_kv_head():
+    """At tensor parallel 4 each chip multiplies through a quarter of every
+    matrix and reads one of the 4 KV heads. These counts are per chip; the
+    exchange between chips, which the CPU tests leave out, is no work
+    here."""
+    from benchmarks.chip import run_cell as RC
+    m = WK.Dims(d=8, layers=2, heads=4, kv_heads=4, head_dim=2, ffn=16,
+                vocab=32)
+
+    def booked(tp):
+        rec = RC.Record(m, tp, 4)
+        rec.reqs = {1: _req(1, 600, 1024)}
+        rec.traced_tokens = [(1, 0, 5)]
+        RC.book_work(rec, RC.load_family(chipbench_tiny.ROOT, QWEN2))
+        return rec.work
+
+    w1, w4 = booked(1), booked(4)
+    matmuls = 2 * m.layer_matmul_params * m.layers + 2 * m.d * m.vocab
+    assert w1["decode_flops"] - w1["attn_flops"] == 4 * matmuls
+    assert w4["decode_flops"] - w4["attn_flops"] == matmuls
+    assert w4["attn_flops"] * 4 == w1["attn_flops"]
+    assert w4["prefill_flops"] * 4 == w1["prefill_flops"]
+    # K and V of one KV head over each token's context, q and out of 1 of
+    # the 4 query heads, bf16, both layers
+    assert w4["attn_bytes"] == sum((2 * (600 + j) * 2 + 2 * 2) * 2 * 2
+                                   for j in range(1, 5))
+    assert w4["decode_tokens"] == w1["decode_tokens"] == 4
 
 
 def test_roofline_share_cannot_pass_the_kernel_time():
