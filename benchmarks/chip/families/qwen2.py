@@ -1,7 +1,8 @@
 """The Qwen2 family (``"model_type": "qwen2"``): a thin adapter over
 ``weights.py`` (seeded weights), ``reference.py`` (the float32 forward and
-its fp8 control) and ``work.py`` (the work counts), which are this
-family's code. ``run_cell.load_family`` lists what a family provides.
+its controls, ``fp8`` and ``tp_local``) and ``work.py`` (the work counts),
+which are this family's code. ``run_cell.load_family`` lists what a
+family provides.
 """
 from __future__ import annotations
 

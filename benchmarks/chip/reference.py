@@ -9,12 +9,19 @@ float32, and every matmul runs at ``highest`` precision. Rows go through
 in groups of ``rows``, one layer at a time, so a 7B forward fits beside
 nothing else on one chip.
 
-``quant="fp8"`` is the control: the same forward with both operands of
-every projection (weights per output column, activations per token)
-scaled to the range of float8_e4m3fn and rounded to it, the step below
-the bfloat16 that the configuration serves in. It has to fail the
-comparison. (int8, symmetric per column and per token, was tried first
-and did not separate from sound bf16 runs at the test size.)
+There are two controls, each a ``quant`` name, that have to fail the
+comparison. ``"fp8"``: the same forward with both operands of every
+projection (weights per output column, activations per token) scaled to
+the range of float8_e4m3fn and rounded to it, the step below the bfloat16
+that the configuration serves in. (int8, symmetric per column and per
+token, was tried first and did not separate from sound bf16 runs at the
+test size.) ``"tp_local"``, for a tensor-parallel cell (the
+configuration's ``tp`` > 1): the same float32 forward, except that each
+layer's attention output projection and MLP down projection keep only the
+first chip's part of their sum (its ``heads / tp`` query heads, which
+attend over its ``kv_heads / tp`` KV heads, and its ``ffn / tp`` MLP
+columns): what that chip computes when the all-reduce after each of the
+two projections is left out.
 """
 from __future__ import annotations
 
@@ -43,6 +50,15 @@ def _mm(x, w, quant):
     if quant != "fp8":
         raise ValueError(f"unknown control precision {quant!r}")
     return jnp.einsum("...d,df->...f", _f8(x, -1), _f8(w, 0))
+
+
+def _part(x, w, quant, parts):
+    """``x @ w`` summed over the contraction's first ``1 / parts`` alone:
+    one chip's partial sum, with no all-reduce, where ``parts`` > 1."""
+    if parts == 1:
+        return _mm(x, w, quant)
+    n = w.shape[0] // parts
+    return _mm(x[..., :n], w[:n], quant)
 
 
 def _rms(x, w, eps):
@@ -77,8 +93,9 @@ def _attention(q, k, v):
     return jnp.concatenate(outs, 1).reshape(B, S, H * dh)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "eps", "theta", "quant"))
-def _layer(x, key, l, *, m: Dims, eps, theta, quant):
+@functools.partial(jax.jit,
+                   static_argnames=("m", "eps", "theta", "quant", "parts"))
+def _layer(x, key, l, *, m: Dims, eps, theta, quant, parts):
     w = jax.tree.map(lambda a: a.astype(jnp.float32), W.layer(key, l, m))
     B, S, _ = x.shape
     h = _rms(x, w["ln1"], eps)
@@ -88,11 +105,11 @@ def _layer(x, key, l, *, m: Dims, eps, theta, quant):
     v = (_mm(h, w["wv"], quant) + w["bv"]).reshape(B, S, m.kv_heads,
                                                     m.head_dim)
     a = _attention(_rope(q, theta), _rope(k, theta), v)
-    x = x + _mm(a, w["wo"], quant)
+    x = x + _part(a, w["wo"], quant, parts)
     h = _rms(x, w["ln2"], eps)
     gate, up = jnp.split(w["w_up"], 2, axis=-1)
     g = jax.nn.silu(_mm(h, gate, quant)) * _mm(h, up, quant)
-    return x + _mm(g, w["w_down"], quant)
+    return x + _part(g, w["w_down"], quant, parts)
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
@@ -109,16 +126,24 @@ def _head(x, key, *, m: Dims, eps, quant):
 def logits(seed: int, model: dict, tokens: np.ndarray, rows_at: np.ndarray,
            quant=None) -> np.ndarray:
     """float32 logits of ``tokens`` ((B, S) ids) at positions ``rows_at``
-    ((B, T), each < S): (B, T, vocab). One call is one group of rows; the
-    caller picks the group size."""
+    ((B, T), each < S): (B, T, vocab), under the control ``quant`` names
+    (None for none). One call is one group of rows; the caller picks the
+    group size."""
     m = Dims.of(model)
     eps, theta = float(model["rms_norm_eps"]), float(model["rope_theta"])
     key = W.seed_key(seed)
+    parts = 1
+    if quant == "tp_local":
+        quant, parts = None, int(model.get("tp", 1))
+        if parts < 2 or m.heads % parts or m.kv_heads % parts \
+                or m.ffn % parts:
+            raise ValueError(f"tp_local needs a tensor-parallel model; "
+                             f"tp={parts} of {m}")
     with jax.default_matmul_precision("highest"):
         x = _embed(jnp.asarray(tokens), key, m=m)
         for l in range(m.layers):
             x = _layer(x, key, jnp.int32(l), m=m, eps=eps, theta=theta,
-                       quant=quant)
+                       quant=quant, parts=parts)
         x = jnp.take_along_axis(x, jnp.asarray(rows_at)[..., None], axis=1)
         return _head(x, key, m=m, eps=eps, quant=quant)
 
@@ -135,16 +160,16 @@ def _gaps(ref, served, other):
 
 
 def served_gaps(seed: int, model: dict, requests, *, rows: int,
-                seq_len: int, out_len: int, control=None):
-    """Widest gap, over every served token of ``requests``, by which the
-    served token's reference logit lies below the reference's best; with
-    ``control`` (a ``quant`` name) also the widest gap of the token that
-    control puts first at the same positions. Each request is (prefilled ids, served
-    ids): the ids as the runtime prefilled them (pad included) and the
-    tokens it delivered, the first from the prefill. Every group is padded
-    to ``rows`` x ``seq_len`` ids and ``out_len`` served tokens, so that
-    one set of programs serves every run."""
-    worst, worst_ctrl = 0.0, 0.0
+                seq_len: int, out_len: int, controls: tuple = ()):
+    """(widest gap, over every served token of ``requests``, by which the
+    served token's reference logit lies below the reference's best; for
+    each name in ``controls``, the widest gap of the token that control
+    puts first at the same positions). Each request is (prefilled ids,
+    served ids): the ids as the runtime prefilled them (pad included) and
+    the tokens it delivered, the first from the prefill. Every group is
+    padded to ``rows`` x ``seq_len`` ids and ``out_len`` served tokens, so
+    that one set of programs serves every run."""
+    worst, worst_ctrl = 0.0, [0.0] * len(controls)
     for g0 in range(0, len(requests), rows):
         grp = requests[g0:g0 + rows]
         tlen = out_len
@@ -162,11 +187,13 @@ def served_gaps(seed: int, model: dict, requests, *, rows: int,
             served[i, :n] = s
             mask[i, :n] = True
         ref = logits(seed, model, toks, at)
-        other = logits(seed, model, toks, at, quant=control) if control \
-            else ref
-        g_s, g_o = (np.asarray(a) for a in _gaps(ref, jnp.asarray(served),
-                                                 other))
-        worst = max(worst, float(g_s[mask].max()))
-        worst_ctrl = max(worst_ctrl, float(g_o[mask].max()))
-        del ref, other
-    return (worst, worst_ctrl) if control else (worst, None)
+        for i, c in enumerate(controls or (None,)):
+            other = logits(seed, model, toks, at, quant=c) if c else ref
+            g_s, g_o = (np.asarray(a) for a in _gaps(
+                ref, jnp.asarray(served), other))
+            worst = max(worst, float(g_s[mask].max()))
+            if c:
+                worst_ctrl[i] = max(worst_ctrl[i], float(g_o[mask].max()))
+            del other
+        del ref
+    return worst, tuple(worst_ctrl)

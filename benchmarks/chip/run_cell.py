@@ -127,8 +127,9 @@ def load_family(root: pathlib.Path, config: dict, where="the config"):
     if the program's ``ArchConfig`` differs from the file;
     ``seed_key(seed)`` and ``program_params(key, model, dtype)``, the
     weights in the program's parameter tree; ``served_gaps(seed, model,
-    requests, *, rows, seq_len, out_len, control)``, the float32 reference
-    comparison and its control; and the work the traffic asked for,
+    requests, *, rows, seq_len, out_len, controls)``, the float32
+    reference comparison, which returns the served tokens' widest gap and
+    a tuple of each named control's; and the work the traffic asked for,
     ``prefill_flops(dims, plen, tp)``, ``decode_token_flops(dims, ctx, tp)``
     and ``attn_decode(dims, ctx, tp)`` (flops, bytes), per chip."""
     kind = config.get("model_type")
@@ -523,16 +524,19 @@ def sample(rec: Record, seed: int, k: int) -> List[Req]:
 
 def compare(cell: Cell, seed: int, picked: List[Req], control=None):
     """(widest served-token gap, the control's widest gap or None), by the
-    family's reference."""
+    family's reference; ``control`` is one name the family's reference
+    knows (``"fp8"``, ``"tp_local"``) or None."""
     if not picked:
         raise RuntimeError("no finished request in the window to check")
     lb_max = pow2_at_least(cell.mix["prompt_tokens"][1])
     out_hi = cell.mix["output_tokens"][1]
-    return cell.family.served_gaps(
+    gap, ctrl = cell.family.served_gaps(
         seed, cell.config, [(r.prompt, np.asarray(r.tokens, np.int32))
                             for r in picked],
         rows=int(cell.sizing["reference_rows"]),
-        seq_len=lb_max + out_hi - 1, out_len=out_hi, control=control)
+        seq_len=lb_max + out_hi - 1, out_len=out_hi,
+        controls=(control,) if control else ())
+    return gap, (ctrl[0] if control else None)
 
 
 def free(rep: Replica) -> None:
@@ -606,8 +610,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, cfg=None,
     """One run; returns the result object. ``on_chip=False`` (the tests)
     leaves the persistent compilation cache off, skips the check that the
     kernels resolved to Pallas and reads no table of peaks. ``control``
-    (``"fp8"``) puts the lower-precision reference in the program's place
-    for the checks, which it has to fail."""
+    puts a broken reference in the program's place for the checks, which
+    it has to fail: ``"fp8"``, the reference in float8, or ``"tp_local"``
+    (a tensor-parallel cell), the reference with the exchange between
+    chips left out."""
     import jax
     from repro.kernels import ops as OPS
     from repro.launch.compile_cache import enable_compile_cache

@@ -146,8 +146,8 @@ def program_params(key, model, dtype):
 
 
 def served_gaps(seed, model, requests, *, rows, seq_len, out_len,
-                control=None):
-    return 0.0, None
+                controls=()):
+    return 0.0, (0.0,) * len(controls)
 
 
 def prefill_flops(m, plen, tp=1):

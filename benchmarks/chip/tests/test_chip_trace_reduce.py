@@ -36,6 +36,10 @@ def test_kernel_and_program_time_by_name():
     assert top["jit_block/paged_decode_attention.3"] == pytest.approx(1.0)
     assert TRC.op_name("%fusion.12 = bf16[2]{0} fusion(%p)") == "fusion.12"
     assert t.op_s(0, "paged_decode_attention") == pytest.approx(1.0)
+    # an operation that takes the kernel's result names it as an operand
+    t.ops.append(TRC.Op(0, "%fusion.4 = bf16[8] fusion(%paged_decode_"
+                        "attention.3)", "jit_block", 2.5, 0.5))
+    assert t.op_s(0, "paged_decode_attention") == pytest.approx(1.0)
     assert t.program_s(0, "jit_block") == pytest.approx(1.5)
     assert t.program_s(0, "jit_admit") == pytest.approx(1.5)
 
@@ -45,7 +49,10 @@ def test_collective_time_is_a_union_on_the_chip():
     t.ops += [TRC.Op(0, "%all-reduce.1 = f32[8] all-reduce(%x)", "jit_block",
                      1.2, 0.2),
               TRC.Op(0, "%all-reduce-start.2 = f32[8] all-reduce-start(%y)",
-                     "jit_block", 1.3, 0.2)]
+                     "jit_block", 1.3, 0.2),
+              # a consumer of the all-reduce, not a collective
+              TRC.Op(0, "%fusion.7 = f32[8] fusion(%all-reduce.1)",
+                     "jit_block", 1.6, 0.4)]
     assert t.collective_s(0) == pytest.approx(0.3)
     assert t.busy_s(0) == pytest.approx(3.0)
 

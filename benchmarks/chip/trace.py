@@ -88,10 +88,10 @@ class Trace:
         return t
 
     def op_s(self, chip: int, name: str) -> float:
-        """Seconds of the chip's operations whose name contains ``name``
-        (a kernel), inside the window."""
+        """Seconds of the chip's operations whose own name (not their
+        operands') contains ``name`` (a kernel), inside the window."""
         return self._sum(o for o in self.ops
-                         if o.chip == chip and name in o.name)
+                         if o.chip == chip and name in op_name(o.name))
 
     def program_s(self, chip: int, prefix: str) -> float:
         """Seconds the chip spent in programs whose name starts with
@@ -109,9 +109,10 @@ class Trace:
 
     def collective_s(self, chip: int) -> float:
         """Seconds in which a collective ran on the chip (a union, so
-        overlapping collectives count once)."""
+        overlapping collectives count once), by the operation's own name:
+        an operation that takes a collective's result names it too."""
         return sum(b - a for a, b in self.intervals(
-            chip, lambda o: COLLECTIVE.search(o.name)))
+            chip, lambda o: COLLECTIVE.search(op_name(o.name))))
 
     def top_ops(self, n: int = 10) -> List[list]:
         """The operations that took most device time, averaged over chips,
